@@ -40,6 +40,9 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
+
     if args.track:
         from . import track
         track.main(["--seed", str(args.seed),

@@ -366,6 +366,9 @@ def main(argv: Optional[List[str]] = None) -> None:
                     help="collect + emit but never fail on regression")
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
+
     print("== benchmark trajectory point (smoke size) ==", flush=True)
     point = collect(seed=args.seed, dryrun_dir=args.dryrun_dir,
                     trials=args.trials)

@@ -6,8 +6,11 @@ through one GraphSession (shared partition cache, cold/warm load split).
     PYTHONPATH=src python examples/serve_queries.py --engine traditional -p 4
     PYTHONPATH=src python examples/serve_queries.py --cache-parts 2 \
         --max-answers 5 --json report.json
-    XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+    JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4 \
         PYTHONPATH=src python examples/serve_queries.py --engine mapreduce
+
+The last line is the CPU form of MapReduceMP (four virtual devices); on a
+host with four TPU chips drop both variables.
 
 Delegates to repro.launch.serve (the real launcher) with demo defaults.
 """

@@ -44,9 +44,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
 from .engine import EngineConfig, _expand_classify
 from .graph import PartitionedGraph, WILDCARD
 from .heuristics import MAX_SN, MAX_YIELD, MIN_SN, RANDOM_SN
@@ -70,6 +69,12 @@ class MapReduceMPResult:
     # into QueryState.observe_yield, surfaced for the session profile
     completed_from: np.ndarray = None   # [P] int64
     spawned_from: np.ndarray = None     # [P] int64
+
+
+def make_part_mesh(k: int) -> Mesh:
+    """The 1-D ``("part",)`` mesh MapReduceMP runs on: one device per
+    partition."""
+    return jax.make_mesh((k,), ("part",), axis_types=(AxisType.Auto,))
 
 
 def _heuristic_id(h: str) -> int:
@@ -390,8 +395,8 @@ class MapReduceMPEngine:
             P(),                                # answer budget (replicated)
         )
         out_specs = (pspec, pspec, pspec, pspec, pspec, pspec, pspec)
-        fn = shard_map(device_fn, mesh=self.mesh, in_specs=in_specs,
-                       out_specs=out_specs, check_vma=False)
+        fn = jax.shard_map(device_fn, mesh=self.mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         return jax.jit(fn)
 
     def run(self, plan: Plan, seed: int = 0,

@@ -219,8 +219,7 @@ class GraphSession:
                 pg, self._processors, self.config, store=self.store,
                 tracer=self.tracer, profiler=self.profiler)
         else:
-            from ..compat import make_part_mesh
-            from .mapreduce_mp import MapReduceMPEngine
+            from .mapreduce_mp import MapReduceMPEngine, make_part_mesh
             mesh = self._mesh
             if mesh is None:
                 mesh = make_part_mesh(pg.k)

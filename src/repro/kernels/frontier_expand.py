@@ -6,8 +6,9 @@ step's predicates.  This kernel fuses the whole match:
 
   * one row-gather of the 6 ELL tables per binding, expressed as a
     scalar-prefetch BlockSpec index_map (the Mosaic "gather rows" idiom used
-    by MoE kernels): block (1, W) of each [Np, W] table, block index taken
-    from the prefetched ``lidx`` scalar vector;
+    by MoE kernels): block (None, 1, W) of each table viewed as
+    [Np, 1, W], block index taken from the prefetched ``lidx`` scalar
+    vector;
   * all predicate evaluation (edge label, direction, dst label, dst value
     comparison, injectivity, cycle closure) as branchless VPU ops on the
     (1, W) tile in VMEM.
@@ -104,7 +105,7 @@ def _kernel(lidx_ref, pint_ref, pflt_ref, rows_ref,      # scalar prefetch (SMEM
 def frontier_expand_pallas(lidx, pint, pflt, rows,
                            ell_dst, ell_label, ell_dir,
                            ell_dlab, ell_dval, ell_dgid,
-                           *, interpret: bool = True):
+                           *, interpret: bool):
     """Raw kernel invocation; ops.frontier_expand is the public wrapper.
 
     lidx [EB] int32 (clipped to [0, Np)), pint [EB, 8] int32, pflt [EB] f32,
@@ -115,8 +116,10 @@ def frontier_expand_pallas(lidx, pint, pflt, rows,
     Np, W = ell_dst.shape
     Q = rows.shape[1]
 
-    ell_spec = pl.BlockSpec((1, W), lambda i, lidx_r, *_: (lidx_r[i], 0))
-    out_spec = pl.BlockSpec((1, W), lambda i, *_: (i, 0))
+    # one-row blocks over [N, 1, W] views: see fused_frontier.py
+    ell_spec = pl.BlockSpec((None, 1, W),
+                            lambda i, lidx_r, *_: (lidx_r[i], 0, 0))
+    out_spec = pl.BlockSpec((None, 1, W), lambda i, *_: (i, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,           # lidx, pint, pflt, rows -> SMEM
@@ -125,12 +128,12 @@ def frontier_expand_pallas(lidx, pint, pflt, rows,
         out_specs=[out_spec, out_spec],
     )
     kernel = functools.partial(_kernel, q_pad=Q)
+    shp = jax.ShapeDtypeStruct((EB, 1, W), jnp.int32)
+    tables = (ell_dst, ell_label, ell_dir, ell_dlab, ell_dval, ell_dgid)
     ok, dg = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((EB, W), jnp.int32),
-                   jax.ShapeDtypeStruct((EB, W), jnp.int32)],
+        out_shape=[shp, shp],
         interpret=interpret,
-    )(lidx, pint, pflt, rows,
-      ell_dst, ell_label, ell_dir, ell_dlab, ell_dval, ell_dgid)
-    return ok, dg
+    )(lidx, pint, pflt, rows, *(t.reshape(Np, 1, W) for t in tables))
+    return ok.reshape(EB, W), dg.reshape(EB, W)

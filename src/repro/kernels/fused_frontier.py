@@ -26,7 +26,8 @@ scalar cases (the next frontier is an already-bound vertex) ride in as
 prefetched SMEM scalars.  The kernel itself therefore still performs NO
 data-dependent gathers: each grid step touches eight (1, W) VMEM tiles
 selected by the scalar-prefetch ``lidx`` BlockSpec index map, exactly the
-Mosaic row-gather idiom of ``frontier_expand.py``.
+Mosaic row-gather idiom of ``frontier_expand.py`` (tables viewed as
+[Np, 1, W] so a one-row block meets Mosaic's tiling rule).
 
 Layout notes (TPU target):
   * W padded to a lane multiple (128) by the ops.py wrapper,
@@ -148,7 +149,7 @@ def fused_frontier_pallas(lidx, pint, pflt, rows,
                           ell_dst, ell_label, ell_dir,
                           ell_dlab, ell_dval, ell_dgid,
                           ell_dlidx, ell_downer,
-                          *, interpret: bool = True):
+                          *, interpret: bool):
     """Raw kernel invocation; ops.fused_frontier is the public wrapper.
 
     lidx [EB] int32 (clipped to [0, Np)), pint [EB, 12] int32, pflt [EB]
@@ -159,8 +160,13 @@ def fused_frontier_pallas(lidx, pint, pflt, rows,
     Np, W = ell_dst.shape
     Q = rows.shape[1]
 
-    ell_spec = pl.BlockSpec((1, W), lambda i, lidx_r, *_: (lidx_r[i], 0))
-    out_spec = pl.BlockSpec((1, W), lambda i, *_: (i, 0))
+    # Mosaic tiles the last two block dims by (8, 128) unless a dim spans
+    # its whole array, so a (1, W) row block of an [Np, W] table is
+    # refused.  Tables are viewed as [Np, 1, W] and outputs as [EB, 1, W]:
+    # the one-row block (None, 1, W) then spans the second-minor dim.
+    ell_spec = pl.BlockSpec((None, 1, W),
+                            lambda i, lidx_r, *_: (lidx_r[i], 0, 0))
+    out_spec = pl.BlockSpec((None, 1, W), lambda i, *_: (i, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,           # lidx, pint, pflt, rows -> SMEM
@@ -169,12 +175,13 @@ def fused_frontier_pallas(lidx, pint, pflt, rows,
         out_specs=[out_spec] * 6,
     )
     kernel = functools.partial(_kernel, q_pad=Q)
-    shp = jax.ShapeDtypeStruct((EB, W), jnp.int32)
-    return pl.pallas_call(
+    shp = jax.ShapeDtypeStruct((EB, 1, W), jnp.int32)
+    tables = (ell_dst, ell_label, ell_dir, ell_dlab, ell_dval, ell_dgid,
+              ell_dlidx, ell_downer)
+    outs = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[shp] * 6,
         interpret=interpret,
-    )(lidx, pint, pflt, rows,
-      ell_dst, ell_label, ell_dir, ell_dlab, ell_dval, ell_dgid,
-      ell_dlidx, ell_downer)
+    )(lidx, pint, pflt, rows, *(t.reshape(Np, 1, W) for t in tables))
+    return [o.reshape(EB, W) for o in outs]

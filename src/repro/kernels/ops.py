@@ -3,9 +3,10 @@
 Each wrapper:
   * adapts engine-level arguments to the kernel's packed layout,
   * pads the lane dimension to 128 multiples (TPU tile alignment),
-  * selects interpret mode automatically off-TPU (the kernels TARGET TPU;
-    interpret=True executes the kernel body in Python on CPU so correctness
-    is validated everywhere),
+  * compiles the kernel on TPU and interprets it on the CPU backend only
+    (interpret mode executes the kernel body in Python, so correctness is
+    validated without a chip); any other backend is an error, never a
+    silent fall back to the interpreter,
   * has a pure-jnp twin in ref.py used by the tests as the oracle.
 """
 from __future__ import annotations
@@ -27,8 +28,16 @@ from .label_histogram import label_histogram_pallas
 LANE = 128
 
 
-def on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret() -> bool:
+    """True on the CPU backend, False on TPU; raises on any other backend."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"the Pallas kernels target TPU; backend {backend!r} "
+                       f"can neither compile them nor should it interpret "
+                       f"them (use JAX_PLATFORMS=cpu for interpret mode)")
 
 
 def _round_up(x: int, m: int) -> int:
@@ -38,14 +47,12 @@ def _round_up(x: int, m: int) -> int:
 def frontier_expand(rows_b, step_b, lidx_b, m,
                     ell_dst, ell_label, ell_dir,
                     ell_dlab, ell_dval, ell_dgid,
-                    plan, n_steps, *, interpret=None):
+                    plan, n_steps):
     """Engine-facing adapter with the same signature/semantics as the jnp
     match in engine._match_tile_jnp (minus row construction).
 
     Returns (ok [EB, W] bool, dg [EB, W] int32) for the ORIGINAL width W.
     """
-    if interpret is None:
-        interpret = not on_tpu()
     EB = rows_b.shape[0]
     Np, W = ell_dst.shape
     S = plan.src_slot.shape[0]
@@ -78,7 +85,7 @@ def frontier_expand(rows_b, step_b, lidx_b, m,
     ok, dg = frontier_expand_pallas(
         lidx, pint, pflt, rows_b.astype(jnp.int32),
         ell_dst, ell_label, ell_dir, ell_dlab, ell_dval, ell_dgid,
-        interpret=interpret)
+        interpret=_interpret())
     return ok[:, :W].astype(bool), dg[:, :W]
 
 
@@ -160,7 +167,7 @@ def fused_frontier(rows_b, step_b, lidx_b, m,
                    ell_dlab, ell_dval, ell_dgid,
                    ell_dlidx, ell_downer,
                    g2l_row, owner, n_core,
-                   plan, n_steps, *, interpret=None):
+                   plan, n_steps):
     """Engine-facing adapter for the fused expand+classify kernel.
 
     Same adapter contract as frontier_expand, plus the two denormalized
@@ -168,8 +175,6 @@ def fused_frontier(rows_b, step_b, lidx_b, m,
     n_core context.  Returns six [EB, W] arrays for the ORIGINAL width W:
     (ok, done, keep, out) bool, (dg, dest) int32.
     """
-    if interpret is None:
-        interpret = not on_tpu()
     Np, W = ell_dst.shape
 
     pint, pflt, _ = _fused_params(rows_b, step_b, m, g2l_row, owner, n_core,
@@ -193,7 +198,7 @@ def fused_frontier(rows_b, step_b, lidx_b, m,
         lidx, pint, pflt, rows_b.astype(jnp.int32),
         ell_dst, ell_label, ell_dir, ell_dlab, ell_dval, ell_dgid,
         ell_dlidx, ell_downer,
-        interpret=interpret)
+        interpret=_interpret())
     return (ok[:, :W].astype(bool), dg[:, :W], done[:, :W].astype(bool),
             keep[:, :W].astype(bool), outm[:, :W].astype(bool), dest[:, :W])
 
@@ -216,9 +221,7 @@ def fused_frontier_ref(rows_b, step_b, lidx_b, m,
         plan.closes_cycle[s], plan.src_slot[s2], n_steps)
 
 
-def label_histogram(node_label, node_value, core_mask, label, value_op, value,
-                    *, interpret=None):
-    if interpret is None:
-        interpret = not on_tpu()
+def label_histogram(node_label, node_value, core_mask, label, value_op, value):
     return label_histogram_pallas(node_label, node_value, core_mask,
-                                  label, value_op, value, interpret=interpret)
+                                  label, value_op, value,
+                                  interpret=_interpret())

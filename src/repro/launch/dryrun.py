@@ -145,7 +145,9 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         except Exception as e:
             rec["hlo_save_error"] = repr(e)
     info = hlo_analysis.analyze_compiled(compiled, lowered)
-    terms = hlo_analysis.roofline_from_info(info)
+    # the dry run models a v5e pod on virtual CPU devices
+    terms = hlo_analysis.roofline_from_info(
+        info, hlo_analysis.CHIP_PEAKS["TPU v5 lite"])
     mf = model_flops(cfg, shape.kind, shape.batch, shape.seq)
     hlo_total = terms.device_flops * n_chips
     rec.update({

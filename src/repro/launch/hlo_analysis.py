@@ -12,18 +12,33 @@ accounting:
   collective-permute : operand bytes
 
 Shapes in the partitioned module are already per-shard, so sums are
-per-device.  Hardware model (TPU v5e): 197 TFLOP/s bf16, 819 GB/s HBM,
-~50 GB/s ICI per chip.
+per-device.  The hardware model is ``CHIP_PEAKS``, keyed by the running
+device's ``device_kind``; a device not in it gets no bound.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict
+from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12      # bf16 per chip
-HBM_BW = 819e9           # bytes/s per chip
-ICI_BW = 50e9            # bytes/s per chip (link-level)
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    """Published peaks of one chip."""
+
+    flops: float       # bf16 FLOP/s
+    hbm_bw: float      # HBM bytes/s
+    ici_bw: float      # chip-to-chip interconnect bytes/s
+    source: str
+
+
+# keyed by jax ``Device.device_kind``
+CHIP_PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8,
+        source="Google Cloud TPU documentation, 'TPU v5e': 197 TFLOP/s "
+               "bf16, 819 GB/s HBM, 1,600 Gbit/s ICI per chip"),
+}
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2, "bf16": 2,
@@ -87,31 +102,41 @@ def count_collective_ops(hlo_text: str) -> Dict[str, int]:
 
 @dataclasses.dataclass
 class RooflineTerms:
+    """Roofline of one program on one chip.  With ``peaks=None`` (a device
+    ``CHIP_PEAKS`` does not list) every time is None and ``dominant`` is
+    "not measured"."""
+
     device_flops: float
     device_bytes: float
     device_coll_bytes: float
+    peaks: Optional[ChipPeaks]
 
     @property
-    def t_compute(self) -> float:
-        return self.device_flops / PEAK_FLOPS
+    def t_compute(self) -> Optional[float]:
+        return None if self.peaks is None else self.device_flops / self.peaks.flops
 
     @property
-    def t_memory(self) -> float:
-        return self.device_bytes / HBM_BW
+    def t_memory(self) -> Optional[float]:
+        return None if self.peaks is None else self.device_bytes / self.peaks.hbm_bw
 
     @property
-    def t_collective(self) -> float:
-        return self.device_coll_bytes / ICI_BW
+    def t_collective(self) -> Optional[float]:
+        return (None if self.peaks is None
+                else self.device_coll_bytes / self.peaks.ici_bw)
 
     @property
     def dominant(self) -> str:
+        if self.peaks is None:
+            return "not measured"
         terms = {"compute": self.t_compute, "memory": self.t_memory,
                  "collective": self.t_collective}
         return max(terms, key=terms.get)
 
     @property
-    def t_bound(self) -> float:
+    def t_bound(self) -> Optional[float]:
         """Roofline lower bound on step time (perfect overlap)."""
+        if self.peaks is None:
+            return None
         return max(self.t_compute, self.t_memory, self.t_collective)
 
     def as_dict(self) -> Dict[str, float]:
@@ -172,9 +197,11 @@ def analyze_compiled(compiled, lowered=None) -> Dict[str, object]:
     return info
 
 
-def roofline_from_info(info: Dict[str, object]) -> RooflineTerms:
+def roofline_from_info(info: Dict[str, object],
+                       peaks: Optional[ChipPeaks]) -> RooflineTerms:
     return RooflineTerms(
         device_flops=float(info.get("flops", 0.0)),
         device_bytes=float(info.get("bytes_accessed", 0.0)),
         device_coll_bytes=float(info["collective_bytes"]["total"]),
+        peaks=peaks,
     )

@@ -22,7 +22,6 @@ import numpy as np
 
 def make_production_mesh(*, multi_pod: bool = False):
     import jax
-    from repro.compat import make_mesh
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     n = int(np.prod(shape))
@@ -31,16 +30,19 @@ def make_production_mesh(*, multi_pod: bool = False):
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(devs)} — run via "
             f"launch/dryrun.py (which sets xla_force_host_platform_device_count)")
-    return make_mesh(shape, axes, devices=devs[:n])
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=devs[:n])
 
 
 def make_test_mesh(shape: Tuple[int, ...] = (1, 1),
                    axes: Tuple[str, ...] = ("data", "model")):
     """A trivial mesh on however many devices exist (CPU tests)."""
     import jax
-    from repro.compat import make_mesh
     n = int(np.prod(shape))
-    return make_mesh(shape, axes, devices=jax.devices()[:n])
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+                         devices=jax.devices()[:n])
 
 
 def dp_axes(mesh) -> Tuple[str, ...]:

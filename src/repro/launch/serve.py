@@ -53,10 +53,15 @@ name the same dataset/scale/seed (the assignment length is validated).
         --scheme ecosocial --engine opat --heuristic max-sn \
         --max-answers 5 --cache-parts 2 --json report.json
 
-MapReduceMP needs one device per partition; run with
-    XLA_FLAGS=--xla_force_host_platform_device_count=4
-(this driver, unlike dryrun.py, leaves device count to the caller so the
-other engines see the real machine).
+MapReduceMP needs one device per partition.  On CPU, give JAX virtual
+devices: ``JAX_PLATFORMS=cpu
+XLA_FLAGS=--xla_force_host_platform_device_count=4``.  On TPU, run on a
+host with one chip per partition (k=4 on four chips).  The other engines
+run on one device either way.
+
+``main`` turns on JAX's persistent compilation cache
+(launch/compile_cache.py): ``JAX_COMPILATION_CACHE_DIR`` where set, else
+``.jax_cache/`` in the checkout.
 """
 from __future__ import annotations
 
@@ -64,6 +69,7 @@ import argparse
 import json
 import sys
 import time
+from typing import Optional
 
 import numpy as np
 
@@ -98,6 +104,23 @@ def load_dataset(name: str, scale: float, seed: int):
     else:
         raise ValueError(name)
     return g, load_queries(name, g, seed)
+
+
+def oracle_match(answers: np.ndarray, ref: np.ndarray,
+                 budget: Optional[int]) -> bool:
+    """``--verify``'s test of one served answer set against the oracle's
+    ``ref`` rows: the same set when exhaustive; under a budget K, real
+    answers only and at least min(K, total) of them."""
+    if budget is None:
+        return (answers.shape[0] == ref.shape[0]
+                and (answers.shape[0] == 0
+                     or np.array_equal(np.unique(answers, axis=0), ref)))
+    # budgeted run: every returned row must be a real answer, and each
+    # disjunct returning min(K, total_d) rows means the union can never
+    # fall below min(K, ref_total)
+    refset = {tuple(r) for r in ref}
+    return (all(tuple(r) in refset for r in answers)
+            and answers.shape[0] >= min(budget, ref.shape[0]))
 
 
 def _mutation_soak(session, dqueries, oracle_graph, *, n_deltas: int,
@@ -295,6 +318,9 @@ def main() -> None:
                     help="with --emit-workload: attach slo_class round-"
                          "robin from this comma-separated list")
     args = ap.parse_args()
+
+    from repro.launch import compile_cache
+    compile_cache.enable()
 
     from repro.obs import NULL_TRACER, Tracer
     tracer = Tracer() if args.trace_out else NULL_TRACER
@@ -547,18 +573,7 @@ def main() -> None:
             from repro.core.oracle import match_disjunctive
             ref = match_disjunctive(oracle_graph["g"], dq,
                                     q_pad=answers.shape[1])
-            if budget is None:
-                match = (answers.shape[0] == ref.shape[0]
-                         and (answers.shape[0] == 0
-                              or np.array_equal(np.unique(answers, axis=0),
-                                                ref)))
-            else:
-                # budgeted run: every returned row must be a real answer,
-                # and each disjunct returning min(K, total_d) rows means
-                # the union can never fall below min(K, ref_total)
-                refset = {tuple(r) for r in ref}
-                match = (all(tuple(r) in refset for r in answers)
-                         and answers.shape[0] >= min(budget, ref.shape[0]))
+            match = oracle_match(answers, ref, budget)
             rec["oracle_match"] = bool(match)
             mismatches += int(not match)
             print(f"        oracle: {ref.shape[0]} answers "

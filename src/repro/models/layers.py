@@ -352,8 +352,7 @@ def moe_ffn_tp(x, router_w, w_gate, w_up, w_down, *, top_k: int,
     """
     N, d = x.shape
     E_loc = w_gate.shape[0]
-    from repro.compat import axis_size
-    n_ranks = axis_size(axis)
+    n_ranks = jax.lax.axis_size(axis)
     E = E_loc * n_ranks
     rank = jax.lax.axis_index(axis)
     e_lo = rank * E_loc
@@ -414,8 +413,7 @@ def make_tp_moe_fn(mesh, dp_spec, cfg):
 
     xspec = P(dp_spec, None, None)
     espec = P("model", None, None)
-    from repro.compat import shard_map
-    fn = shard_map(
+    fn = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(xspec, P(), espec, espec, espec),
         out_specs=(xspec, P()),

@@ -18,7 +18,9 @@ bandwidth) are the binding constraint; PR 9 made the system observable in
                       executes), runs ``launch/hlo_cost.analyze_hlo_text``
                       over the HLO, and folds the FLOPs/bytes estimate
                       through the roofline model
-                      (``launch/hlo_analysis.RooflineTerms``).
+                      (``launch/hlo_analysis.RooflineTerms``) with the
+                      peaks of the running device's kind; a kind with no
+                      published peaks gets no bound ("not measured").
                       ``stamp_kernel`` then writes the per-key cost onto
                       every ``kernel.eval`` span, so a trace joins
                       *predicted* cost with *measured* wall time —
@@ -130,27 +132,32 @@ class ResourceProfiler:
         if cached is not None:
             return cached
         cost: Dict[str, Any] = {"flops": 0.0, "bytes": 0.0,
-                                "t_bound_us": 0.0, "dominant": "unknown"}
+                                "t_bound_us": None, "dominant": "not measured"}
         try:
-            from ..launch.hlo_analysis import RooflineTerms
+            import jax
+            from ..launch.hlo_analysis import CHIP_PEAKS, RooflineTerms
             from ..launch.hlo_cost import analyze_hlo_text
+            kind = jax.devices()[0].device_kind
             text = fn.lower(*args).as_text(dialect="hlo")
             info = analyze_hlo_text(text)
             terms = RooflineTerms(
                 device_flops=float(info["flops"]),
                 device_bytes=float(info["bytes"]),
-                device_coll_bytes=float(info["collective_bytes_total"]))
+                device_coll_bytes=float(info["collective_bytes_total"]),
+                peaks=CHIP_PEAKS.get(kind))
+            t_bound = terms.t_bound
             cost = {
                 "flops": float(info["flops"]),
                 "bytes": float(info["bytes"]),
                 "bytes_xla_convention": float(info["bytes_xla_convention"]),
-                "t_bound_us": float(terms.t_bound) * 1e6,
+                "device_kind": kind,
+                "t_bound_us": None if t_bound is None else t_bound * 1e6,
                 "dominant": terms.dominant,
             }
             if info.get("warnings"):
                 cost["warnings"] = list(info["warnings"])
         except Exception as e:  # profiling must never break serving
-            cost["cost_error"] = type(e).__name__
+            cost["cost_error"] = f"{type(e).__name__}: {e}"
         self.kernel_costs[skey] = cost
         return cost
 

@@ -11,7 +11,7 @@ Invariants asserted per engine:
 import numpy as np
 import pytest
 
-from repro.compat import make_part_mesh
+from repro.core.mapreduce_mp import make_part_mesh
 from repro.core import (EngineConfig, MAX_SN, MAX_YIELD, OPATEngine, RunRequest,
                         TraditionalMPEngine, build_catalog, build_partitions, generate_plan,
                         match_query, partition_graph)

@@ -76,7 +76,7 @@ def test_mapreduce_single_device_matches_oracle(setup):
     g, pg_4, cat, queries = setup
     # one partition per device; this container has 1 device -> k=1
     pg = build_partitions(g, np.zeros(g.n_nodes, dtype=np.int32), 1)
-    from repro.compat import make_part_mesh
+    from repro.core.mapreduce_mp import make_part_mesh
     mesh = make_part_mesh(1)
     eng = MapReduceMPEngine(pg, mesh, EngineConfig(cap=32768))
     for q in queries:

@@ -6,8 +6,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import make_mesh, shard_map
 from repro.launch.hlo_cost import analyze_hlo_text
+
+
+def _mesh_x():
+    return jax.make_mesh((1,), ("x",), axis_types=(jax.sharding.AxisType.Auto,))
 
 
 def _compile(f, *specs):
@@ -64,10 +67,11 @@ def test_batched_dot_counts_batch_dims():
 
 
 def test_collectives_counted_with_ring_factors():
-    mesh = make_mesh((1,), ("x",))
+    mesh = _mesh_x()
     def f(x):
         return jax.lax.psum(x, "x")
-    sm = jax.jit(shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P()))
+    sm = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("x"), out_specs=P(),
+                               check_vma=False))
     c = sm.lower(jax.ShapeDtypeStruct((1024,), jnp.float32)).compile()
     r = analyze_hlo_text(c.as_text())
     # all-reduce: 2 x operand bytes
@@ -76,14 +80,14 @@ def test_collectives_counted_with_ring_factors():
 
 
 def test_collective_inside_scan_multiplied():
-    mesh = make_mesh((1,), ("x",))
+    mesh = _mesh_x()
     def f(xs):
         def body(c, x):
             return c + jax.lax.psum(x, "x"), None
         out, _ = jax.lax.scan(body, jnp.zeros((64,), jnp.float32), xs)
         return out
-    sm = jax.jit(shard_map(f, mesh=mesh, in_specs=P(None, "x"),
-                           out_specs=P("x")))
+    sm = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(None, "x"),
+                               out_specs=P("x"), check_vma=False))
     c = sm.lower(jax.ShapeDtypeStruct((7, 64), jnp.float32)).compile()
     r = analyze_hlo_text(c.as_text())
     assert r["collective_op_executions"] == pytest.approx(7, abs=0.1)

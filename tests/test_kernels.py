@@ -59,8 +59,7 @@ def test_frontier_expand_matches_ref(EB, W, Q, Np):
     m = rng.random(EB) < 0.8
     n_steps = np.int32(S - 1)
 
-    ok_k, dg_k = frontier_expand(rows, step, lidx, m, *tables, plan, n_steps,
-                                 interpret=True)
+    ok_k, dg_k = frontier_expand(rows, step, lidx, m, *tables, plan, n_steps)
     ok_r, dg_r = frontier_expand_ref(rows, step, lidx, m, *tables, plan, n_steps)
     np.testing.assert_array_equal(np.asarray(ok_k), np.asarray(ok_r))
     # dst gids only meaningful where an edge exists
@@ -77,8 +76,7 @@ def test_label_histogram_matches_ref(Np, label, op):
     node_value[rng.random(Np) < 0.3] = np.nan
     core = (rng.random(Np) < 0.7).astype(np.int32)
     got = label_histogram(node_label, node_value, core,
-                          np.int32(label), np.int32(op), np.float32(0.1),
-                          interpret=True)
+                          np.int32(label), np.int32(op), np.float32(0.1))
     want = ref.label_histogram_ref(node_label, node_value, core.astype(bool),
                                    np.int32(label), np.int32(op),
                                    np.float32(0.1))
@@ -139,8 +137,7 @@ def _fused_both(rng, plan, tables, EB, W, Q, Np, n_steps, m=None):
     if m is None:
         m = rng.random(EB) < 0.8
     got = ops.fused_frontier(rows, step, lidx, m, *tables, dlidx, downer,
-                             g2l_row, owner, n_core, plan, n_steps,
-                             interpret=True)
+                             g2l_row, owner, n_core, plan, n_steps)
     want = ops.fused_frontier_ref(rows, step, lidx, m, *tables,
                                   g2l_row, owner, n_core, plan, n_steps)
     return got, want, lidx
@@ -241,7 +238,7 @@ def test_traditional_mp_end_to_end_with_pallas(small_graph):
 def test_mapreduce_end_to_end_with_pallas(small_graph, K):
     """MapReduceMP runs the fused kernel under shard_map; with a budget the
     single compiled run returns exactly min(K, total) unique answers."""
-    from repro.compat import make_part_mesh
+    from repro.core.mapreduce_mp import make_part_mesh
     from repro.core.mapreduce_mp import MapReduceMPEngine
     _, cat, queries = _pallas_setup(small_graph)
     pg = build_partitions(small_graph,
@@ -297,7 +294,7 @@ def test_mapreduce_yield_counters_surface(small_graph):
     """The compiled MapReduce program carries per-partition completed/
     spawned counters out; a budgeted run is a single compiled call (no
     geometric host re-runs), so requested==returned exactly."""
-    from repro.compat import make_part_mesh
+    from repro.core.mapreduce_mp import make_part_mesh
     from repro.core.mapreduce_mp import MapReduceMPEngine
     _, cat, queries = _pallas_setup(small_graph)
     pg = build_partitions(small_graph,

@@ -22,7 +22,7 @@ SCRIPT = textwrap.dedent("""
     assign = partition_graph(g, 4, "kway_shem")
     pg = build_partitions(g, assign, 4)
     cat = build_catalog(g)
-    from repro.compat import make_part_mesh
+    from repro.core.mapreduce_mp import make_part_mesh
     mesh = make_part_mesh(4)
 
     # (2, MAX_YIELD) gates expansion through the on-device completion-rate

@@ -13,12 +13,15 @@ import subprocess
 import sys
 import time
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import EngineConfig, GraphSession, match_disjunctive
 from repro.core.metrics import RunStats, validate_run_residency
 from repro.data.generators import subgen_like_graph, subgen_queries
+from repro.launch.hlo_analysis import CHIP_PEAKS, RooflineTerms
 from repro.obs import (NULL_PROFILER, NULL_TRACER, MetricsRegistry,
                        ResourceProfiler, SloBurnMonitor, Tracer,
                        ingest_session, resource_profile_snapshot)
@@ -142,8 +145,38 @@ def test_every_kernel_span_carries_cost_attrs(setup, engine, k, key):
     cost = sess.profiler.kernel_costs[key]
     assert "cost_error" not in cost, cost
     assert cost["flops"] > 0 and cost["bytes"] > 0
-    assert cost["t_bound_us"] > 0
-    assert cost["dominant"] in ("compute", "memory", "collective")
+    # the bound comes from the running device's published peaks; a device
+    # with none (the CPU) gets FLOPs and bytes counted but no bound
+    assert cost["device_kind"] == jax.devices()[0].device_kind
+    if cost["device_kind"] in CHIP_PEAKS:
+        assert cost["t_bound_us"] > 0
+        assert cost["dominant"] in ("compute", "memory", "collective")
+    else:
+        assert cost["t_bound_us"] is None
+        assert cost["dominant"] == "not measured"
+
+
+def test_v5e_kind_gets_v5e_peaks(monkeypatch):
+    """A TPU v5e device (kind "TPU v5 lite") is bounded by v5e's published
+    peaks; RooflineTerms without peaks claims no bound."""
+    class _V5e:
+        device_kind = "TPU v5 lite"
+    fn = jax.jit(lambda a, b: a @ b)
+    args = (jax.ShapeDtypeStruct((256, 512), jnp.float32),
+            jax.ShapeDtypeStruct((512, 1024), jnp.float32))
+    monkeypatch.setattr(jax, "devices", lambda *a: [_V5e()])
+    cost = ResourceProfiler().attribute_kernel(("mm", 1), fn, *args)
+    assert "cost_error" not in cost, cost
+    peaks = CHIP_PEAKS["TPU v5 lite"]
+    assert (peaks.flops, peaks.hbm_bw, peaks.ici_bw) == (197e12, 819e9, 200e9)
+    t_compute = cost["flops"] / peaks.flops
+    t_memory = cost["bytes"] / peaks.hbm_bw
+    assert cost["t_bound_us"] == pytest.approx(max(t_compute, t_memory) * 1e6)
+    assert cost["dominant"] == ("compute" if t_compute > t_memory
+                                else "memory")
+    bare = RooflineTerms(device_flops=1.0, device_bytes=1.0,
+                         device_coll_bytes=0.0, peaks=None)
+    assert bare.t_bound is None and bare.dominant == "not measured"
 
 
 def test_attribution_failure_degrades_not_raises():

@@ -279,7 +279,7 @@ def report_cost(spans) -> None:
     print(f"== kernel cost attribution ({len(groups)} compiled buckets) ==")
     print(f"  {'bucket':<20} {'calls':>5} {'steady ms':>10} "
           f"{'pred GFLOP':>10} {'pred GB':>8} {'achieved':>12} "
-          f"{'roofline%':>9}  bound   {'peak dev MB':>11}")
+          f"{'roofline%':>12}  bound   {'peak dev MB':>11}")
     for key in sorted(groups):
         sps = groups[key]
         steady = [sp for sp in sps
@@ -289,18 +289,21 @@ def report_cost(spans) -> None:
         a = sps[0].get("args", {})
         flops = float(a.get("cost_flops", 0.0))
         nbytes = float(a.get("cost_bytes", 0.0))
-        bound_us = float(a.get("cost_t_bound_us", 0.0))
+        bound_us = a.get("cost_t_bound_us")
         dominant = a.get("cost_dominant", "?")
         # achieved throughput from the measured mean; roofline% is how
         # close measurement came to the model's bound (100% = at the
-        # bound; <100% = overhead the roofline doesn't model)
+        # bound; <100% = overhead the roofline doesn't model).  A device
+        # with no published peaks has no bound: "not measured".
         gflops = (flops / mean_us) / 1e3 if mean_us > 0 else 0.0
-        roof = 100.0 * bound_us / mean_us if mean_us > 0 else 0.0
+        roof = ("not measured" if bound_us is None
+                else f"{100.0 * bound_us / mean_us:.2f}%" if mean_us > 0
+                else "0.00%")
         live = max((float(sp.get("args", {}).get("device_live_bytes", 0.0))
                     for sp in sps), default=0.0)
         print(f"  {key:<20} {len(sps):>5} {mean_us / 1e3:>10.3f} "
               f"{flops / 1e9:>10.3f} {nbytes / 1e9:>8.3f} "
-              f"{gflops:>8.2f} GF/s {roof:>8.2f}%  {dominant:<7}"
+              f"{gflops:>8.2f} GF/s {roof:>12}  {dominant:<7}"
               f"{live / 1e6:>11.2f}")
     errs = sorted({(k, g[0].get("args", {}).get("cost_error"))
                    for k, g in groups.items()
