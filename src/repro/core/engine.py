@@ -24,7 +24,8 @@ routes through it (interpret mode on CPU).
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, NamedTuple
+from typing import (Any, Callable, Dict, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +35,20 @@ from .graph import DIR_BACKWARD, DIR_FORWARD, DIR_UNDIRECTED, PartitionArrays, W
 from .plan import PlanArrays
 from .query import QDIR_ANY, QDIR_IN, QDIR_OUT
 from .state import apply_value_op
+
+
+# The evaluator's module name, in HLO and on a device trace's module line,
+# for the single form and every vmapped form alike.
+EVAL_MODULE = "jit_evaluate"
+
+
+def jit_evaluator(fn: Callable) -> Callable:
+    """``jax.jit`` of ``fn`` under the module name ``EVAL_MODULE``, so the
+    name does not hang on whatever Python function is being wrapped."""
+    def evaluate(*args):
+        return fn(*args)
+    evaluate.__name__ = evaluate.__qualname__ = EVAL_MODULE[len("jit_"):]
+    return jax.jit(evaluate)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -320,12 +335,76 @@ def make_partition_evaluator(node_pad: int, ell_width: int, cfg: EngineConfig):
         (_, _, _, cr, cn, orr, os_, od, on, ovf, it, nx) = state
         return EvalResult(cr, cn, orr, os_, od, on, ovf, it, nx)
 
-    return jax.jit(evaluate)
+    return jit_evaluator(evaluate)
 
 
 # ---------------------------------------------------------------------------
 # Host-side helpers shared by the OPAT / TraditionalMP orchestrators
 # ---------------------------------------------------------------------------
+
+class EvalCounts(NamedTuple):
+    """An evaluator call's scalars on the host (one entry per lane for a
+    vmapped call): what the host needs before it reads any row."""
+
+    overflow: np.ndarray
+    comp_n: np.ndarray
+    out_n: np.ndarray
+    n_iters: np.ndarray       # while-loop trips
+    n_expanded: Optional[np.ndarray]  # binding rows expanded (None:
+                                      # the program does not count them)
+
+
+def read_counts(res: EvalResult) -> EvalCounts:
+    """The scalars of an ``EvalResult`` in one ``jax.device_get``: the
+    call's only sync before its rows are read."""
+    return EvalCounts(*jax.device_get((res.overflow, res.comp_n, res.out_n,
+                                       res.n_iters, res.n_expanded)))
+
+
+def host_nbytes(*trees: Any) -> int:
+    """Bytes of the host (numpy) leaves of ``trees``: what a call ships to
+    the device (device-resident leaves count nothing)."""
+    return sum(int(x.nbytes) for x in jax.tree_util.tree_leaves(trees)
+               if isinstance(x, (np.ndarray, np.generic)))
+
+
+def traced_eval(host: Any, key: Any, fn: Callable, args: Sequence[Any],
+                read: Callable[[Any], EvalCounts] = read_counts,
+                **attrs: Any) -> Tuple[Any, EvalCounts]:
+    """One evaluator call as every engine and the scheduler make it: a
+    ``kernel.eval`` span (``attrs`` plus the call's ``n_iters`` and
+    ``n_expanded``) holding an ``eval.launch`` child up to the return of
+    the jitted call, then the one sync that reads the scalars.
+
+    ``host`` carries ``tracer``, ``profiler`` and ``store``.  The
+    profiler attributes ``key`` on its first use (it keeps one cost per
+    key); a compile the call triggers shows as a ``jit.compile`` span
+    under ``eval.launch`` (obs/trace.py)."""
+    with host.tracer.span("kernel.eval", **attrs) as ksp:
+        with host.tracer.span("eval.launch"):
+            host.profiler.attribute_kernel(key, fn, *args)
+            out = fn(*args)
+        counts = read(out)
+        ksp.set(n_iters=int(np.sum(counts.n_iters)))
+        if counts.n_expanded is not None:
+            ksp.set(n_expanded=int(np.sum(counts.n_expanded)))
+        host.profiler.stamp_kernel(ksp, key)
+        host.profiler.sample_device(ksp, host.store)
+    return out, counts
+
+
+def read_rows(res: EvalResult, counts: EvalCounts) -> Dict[str, np.ndarray]:
+    """The row buffers a call filled, in one ``jax.device_get``: the
+    completed rows if any lane completed one, the outgoing rows, steps
+    and destinations if any lane emitted one."""
+    want = {}
+    if np.any(counts.comp_n):
+        want["comp_rows"] = res.comp_rows
+    if np.any(counts.out_n):
+        want.update(out_rows=res.out_rows, out_step=res.out_step,
+                    out_dest=res.out_dest)
+    return jax.device_get(want)
+
 
 def part_to_device_dict(p: PartitionArrays) -> Dict[str, np.ndarray]:
     assert p.ell_dst is not None, "call PartitionArrays.to_ell() first"

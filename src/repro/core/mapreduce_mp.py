@@ -46,10 +46,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
-from .engine import EngineConfig, _expand_classify
+from .engine import (EngineConfig, EvalCounts, _expand_classify, host_nbytes,
+                     traced_eval)
 from .graph import PartitionedGraph, WILDCARD
 from .heuristics import MAX_SN, MAX_YIELD, MIN_SN, RANDOM_SN
-from .metrics import RunStats, l_ideal_for_plan
+from .metrics import RunStats, l_ideal_for_plan, residency
 from .plan import Plan, PlanArrays
 from .runner import RunReport, RunRequest, truncate_answers
 from .state import apply_value_op
@@ -75,6 +76,16 @@ def make_part_mesh(k: int) -> Mesh:
     """The 1-D ``("part",)`` mesh MapReduceMP runs on: one device per
     partition."""
     return jax.make_mesh((k,), ("part",), axis_types=(AxisType.Auto,))
+
+
+def _read_counts(out) -> EvalCounts:
+    """The SPMD program's scalars in one ``jax.device_get``.  Its loop
+    condition is a ``psum``, so every device runs the same trips.  The
+    program does not count the rows it expands."""
+    faa_n, overflow, iters = jax.device_get((out[1], out[2], out[3]))
+    return EvalCounts(overflow=np.any(overflow), comp_n=faa_n,
+                      out_n=np.zeros_like(faa_n), n_iters=np.max(iters),
+                      n_expanded=None)
 
 
 def _heuristic_id(h: str) -> int:
@@ -122,7 +133,6 @@ class MapReduceMPEngine:
         self.tracer = tracer if tracer is not None else NULL_TRACER
         from ..obs.profile import NULL_PROFILER
         self.profiler = profiler if profiler is not None else NULL_PROFILER
-        self._eval_traced = False
 
     # -- the SPMD program ----------------------------------------------------
 
@@ -394,7 +404,7 @@ class MapReduceMPEngine:
             P(),                                # rng seed
             P(),                                # answer budget (replicated)
         )
-        out_specs = (pspec, pspec, pspec, pspec, pspec, pspec, pspec)
+        out_specs = (pspec,) * 7
         fn = jax.shard_map(device_fn, mesh=self.mesh, in_specs=in_specs,
                            out_specs=out_specs, check_vma=False)
         return jax.jit(fn)
@@ -415,39 +425,25 @@ class MapReduceMPEngine:
         load0 = self.store.stats.copy()
         entry = self.store.get_stacked(tuple(range(self.P)),
                                        sharding=self._part_sharding)
-        with self.tracer.span("kernel.eval", engine="mapreduce",
-                              n_parts=self.P) as ksp:
-            if not self._eval_traced:
-                self._eval_traced = True
-                ksp.set(first_call=True)
-                self.profiler.attribute_kernel(
-                    ("mapreduce", "eval"), self._compiled, entry.part,
-                    entry.g2l, self.store.owner, plan_arrays,
-                    np.int32(plan.n_steps), np.int32(seed),
-                    np.int32(min(dev_budget, int(_NO_BUDGET))))
-                with self.tracer.span("kernel.compile", engine="mapreduce"):
-                    out = self._compiled(
-                        entry.part, entry.g2l, self.store.owner, plan_arrays,
-                        np.int32(plan.n_steps), np.int32(seed),
-                        np.int32(min(dev_budget, int(_NO_BUDGET))))
-            else:
-                out = self._compiled(
-                    entry.part, entry.g2l, self.store.owner, plan_arrays,
-                    np.int32(plan.n_steps), np.int32(seed),
-                    np.int32(min(dev_budget, int(_NO_BUDGET))))
-            faa, faa_n, overflow, iters, exhausted, comp, spawn = out
-            faa = np.asarray(faa)          # device sync inside the span
-            faa_n = np.asarray(faa_n)
-            self.profiler.stamp_kernel(ksp, ("mapreduce", "eval"))
-            self.profiler.sample_device(ksp, self.store)
-        if bool(np.asarray(overflow).any()):
+        out, c = traced_eval(
+            self, ("mapreduce", "eval"), self._compiled,
+            (entry.part, entry.g2l, self.store.owner, plan_arrays,
+             np.int32(plan.n_steps), np.int32(seed),
+             np.int32(min(dev_budget, int(_NO_BUDGET)))),
+            read=_read_counts, engine="mapreduce", n_parts=self.P, rows=0)
+        if c.overflow:
             raise RuntimeError(
                 "MapReduceMP buffer overflow; raise cap/quota")
-        rows = [faa[p, : faa_n[p]] for p in range(self.P) if faa_n[p]]
-        answers = (np.unique(np.concatenate(rows), axis=0) if rows
-                   else np.zeros((0, cfg.q_pad), dtype=np.int32))
+        with self.tracer.span("eval.absorb") as asp:
+            faa_n = c.comp_n
+            faa, comp, spawn = jax.device_get((out[0], out[5], out[6]))
+            rows = [faa[p, : faa_n[p]] for p in range(self.P) if faa_n[p]]
+            answers = (np.unique(np.concatenate(rows), axis=0) if rows
+                       else np.zeros((0, cfg.q_pad), dtype=np.int32))
+            if self.tracer.enabled:
+                asp.set(bytes_d2h=host_nbytes(faa, comp, spawn))
         answers = truncate_answers(answers, max_answers)
-        n_iter = int(np.asarray(iters).max())
+        n_iter = int(c.n_iters)
         delta = self.store.stats - load0
         stats = RunStats(query=plan.query.name, scheme=self.pg.scheme,
                          heuristic=self.heuristic,
@@ -455,19 +451,13 @@ class MapReduceMPEngine:
                          n_answers=int(answers.shape[0]),
                          iterations=n_iter,
                          answers_requested=max_answers,
-                         cold_loads=delta.cold_loads,
-                         warm_loads=delta.warm_loads,
-                         prefetch_hits=delta.prefetch_hits,
-                         disk_reads=delta.disk_reads,
-                         read_ahead_hits=delta.read_ahead_hits,
-                         bytes_cold=delta.bytes_cold,
-                         bytes_prefetched=delta.bytes_prefetched,
-                         bytes_disk=delta.bytes_disk,
-                         bytes_host=delta.bytes_host)
+                         eval_iters=n_iter,
+                         rows_expanded=None,
+                         **residency(delta))
         return MapReduceMPResult(
             answers=answers, stats=stats, n_iterations=n_iter,
-            completed_from=np.asarray(comp).astype(np.int64).reshape(-1),
-            spawned_from=np.asarray(spawn).astype(np.int64).reshape(-1))
+            completed_from=comp.astype(np.int64).reshape(-1),
+            spawned_from=spawn.astype(np.int64).reshape(-1))
 
     def run_request(self, req: RunRequest) -> RunReport:
         """The shared ``QueryRunner`` protocol (see core/runner.py).

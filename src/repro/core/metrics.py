@@ -16,7 +16,7 @@ required partition.
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Sequence
+from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
@@ -67,6 +67,12 @@ class RunStats:
     # snapshot, even if a compaction published a newer one mid-run.  None
     # for in-RAM sessions (no generations) and hand-built RunStats.
     generation: Optional[int] = None
+    # the evaluator's work for this run, summed over its calls (lanes of
+    # a batched call count for the query that rode them): while-loop
+    # trips, and binding rows expanded (None where the engine does not
+    # count them: MapReduceMP)
+    eval_iters: int = 0
+    rows_expanded: Optional[int] = 0
 
     @property
     def n_loads(self) -> int:
@@ -77,6 +83,17 @@ class RunStats:
         if self.n_loads == 0:
             return 1.0
         return min(1.0, self.l_ideal / self.n_loads)
+
+
+RESIDENCY_FIELDS = ("cold_loads", "warm_loads", "prefetch_hits",
+                    "disk_reads", "read_ahead_hits", "bytes_cold",
+                    "bytes_prefetched", "bytes_disk", "bytes_host")
+
+
+def residency(delta: Any) -> Dict[str, int]:
+    """The ``RunStats`` residency and byte fields of one run, from the
+    store's ``LoadStats`` delta over it."""
+    return {f: getattr(delta, f) for f in RESIDENCY_FIELDS}
 
 
 def validate_run_residency(stats: RunStats,

@@ -24,15 +24,16 @@ split.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
 
-from .engine import EngineConfig, make_partition_evaluator
+from .engine import (EngineConfig, host_nbytes, jit_evaluator,
+                     make_partition_evaluator, read_rows, traced_eval)
 from .graph import PartitionedGraph
 from .heuristics import MAX_YIELD, rank_partitions
-from .metrics import RunStats, l_ideal_for_plan
+from .metrics import RunStats, l_ideal_for_plan, residency
 from .plan import Plan, PlanArrays
 from .runner import RunReport, RunRequest, truncate_answers
 from .state import BindingBatch, QueryState
@@ -47,22 +48,23 @@ class OPATResult:
 
 
 def absorb_eval_outputs(st: QueryState, pid: int, k: int,
-                        comp_rows: np.ndarray, comp_n: int,
-                        out_rows: np.ndarray, out_step: np.ndarray,
-                        out_dest: np.ndarray, out_n: int) -> None:
-    """Route one evaluator call's outputs into a query's bookkeeping state:
+                        bufs: Dict[str, np.ndarray], comp_n: int, out_n: int,
+                        lane: Tuple[int, ...] = ()) -> None:
+    """Route one evaluator lane's outputs into a query's bookkeeping state:
     completed rows append to the FAA, outgoing continuations land in their
     destination partitions' IMA files (deduped, paper Fig. 4c), and the
-    partition's yield counters update.  Shared by the per-query OPAT loop
-    and the scheduler's batched evaluation (core/scheduler.py), so the
-    paper's bookkeeping cannot diverge between the two paths."""
+    partition's yield counters update.  ``bufs`` holds the host copies of
+    the call's buffers (``engine.read_rows``) and ``lane`` indexes one
+    lane of a vmapped call.  Shared by OPAT, TraditionalMP and the
+    scheduler's batched evaluation (core/scheduler.py), so the paper's
+    bookkeeping cannot diverge between the paths."""
     if comp_n:
-        st.add_answers(np.asarray(comp_rows)[:comp_n])
+        st.add_answers(bufs["comp_rows"][lane][:comp_n])
     st.observe_yield(pid, comp_n, out_n)
     if out_n:
-        rows = np.asarray(out_rows)[:out_n]
-        step = np.asarray(out_step)[:out_n]
-        dest = np.asarray(out_dest)[:out_n]
+        rows = bufs["out_rows"][lane][:out_n]
+        step = bufs["out_step"][lane][:out_n]
+        dest = bufs["out_dest"][lane][:out_n]
         for q in range(k):
             sel = dest == q
             if sel.any():
@@ -96,9 +98,6 @@ class OPATEngine:
         self.tracer = tracer if tracer is not None else NULL_TRACER
         from ..obs.profile import NULL_PROFILER
         self.profiler = profiler if profiler is not None else NULL_PROFILER
-        # flips after the first kernel call so the jit compile shows up as
-        # a one-off "kernel.compile" child span, not steady-state eval time
-        self._eval_traced = False
 
     def batched_evaluator(self):
         """The *plan-batched* partition evaluator: ``vmap`` of the compiled
@@ -112,15 +111,18 @@ class OPATEngine:
         holds one trace per bucket, reused across rounds.  Built lazily:
         per-query serving never pays for it."""
         if self._beval is None:
-            self._beval = jax.jit(jax.vmap(
+            self._beval = jit_evaluator(jax.vmap(
                 self._eval, in_axes=(None, None, None, 0, 0, 0, 0, 0, 0)))
         return self._beval
 
     def _run_partition(self, entry: StoreEntry, plan_arrays: PlanArrays,
                        n_steps: int, batch: BindingBatch, seed_fresh: bool,
-                       st: QueryState) -> None:
+                       st: QueryState) -> Tuple[int, int]:
+        """Evaluate ``batch`` on the loaded partition (in ``cap``-row
+        chunks); returns the evaluator's (trips, rows expanded)."""
         cfg = self.cfg
         pid = int(entry.key)
+        iters = expanded = 0
         chunks: List[BindingBatch] = []
         if batch.n == 0:
             chunks.append(BindingBatch.empty(cfg.q_pad))
@@ -129,48 +131,34 @@ class OPATEngine:
                 chunks.append(BindingBatch(rows=batch.rows[i : i + cfg.cap],
                                            step=batch.step[i : i + cfg.cap]))
         for ci, chunk in enumerate(chunks):
-            in_rows = np.full((cfg.cap, cfg.q_pad), -1, dtype=np.int32)
-            in_step = np.zeros(cfg.cap, dtype=np.int32)
-            in_valid = np.zeros(cfg.cap, dtype=bool)
-            if chunk.n:
-                in_rows[: chunk.n] = chunk.rows
-                in_step[: chunk.n] = chunk.step
-                in_valid[: chunk.n] = True
-            with self.tracer.span("kernel.eval", pid=pid, engine="opat",
-                                  rows=int(chunk.n)) as ksp:
-                if not self._eval_traced:
-                    # the first call traces+compiles the jitted evaluator;
-                    # nest that one-off under its own child span so
-                    # steady-state eval time reads clean
-                    self._eval_traced = True
-                    ksp.set(first_call=True)
-                    self.profiler.attribute_kernel(
-                        ("opat", "eval"), self._eval, entry.part, entry.g2l,
-                        self.store.owner, plan_arrays, np.int32(n_steps),
-                        in_rows, in_step, in_valid,
+            with self.tracer.span("eval.inputs", rows=int(chunk.n)) as isp:
+                in_rows = np.full((cfg.cap, cfg.q_pad), -1, dtype=np.int32)
+                in_step = np.zeros(cfg.cap, dtype=np.int32)
+                in_valid = np.zeros(cfg.cap, dtype=bool)
+                if chunk.n:
+                    in_rows[: chunk.n] = chunk.rows
+                    in_step[: chunk.n] = chunk.step
+                    in_valid[: chunk.n] = True
+                args = (entry.part, entry.g2l, self.store.owner, plan_arrays,
+                        np.int32(n_steps), in_rows, in_step, in_valid,
                         np.bool_(seed_fresh and ci == 0))
-                    with self.tracer.span("kernel.compile", engine="opat"):
-                        res = self._eval(entry.part, entry.g2l,
-                                         self.store.owner,
-                                         plan_arrays, np.int32(n_steps),
-                                         in_rows, in_step, in_valid,
-                                         np.bool_(seed_fresh and ci == 0))
-                else:
-                    res = self._eval(entry.part, entry.g2l, self.store.owner,
-                                     plan_arrays, np.int32(n_steps),
-                                     in_rows, in_step, in_valid,
-                                     np.bool_(seed_fresh and ci == 0))
-                overflow = bool(res.overflow)   # device sync inside the span
-                self.profiler.stamp_kernel(ksp, ("opat", "eval"))
-                self.profiler.sample_device(ksp, self.store)
-            if overflow:
+                if self.tracer.enabled:
+                    isp.set(bytes_h2d=host_nbytes(args))
+            res, c = traced_eval(self, ("opat", "eval"), self._eval, args,
+                                 pid=pid, engine="opat", rows=int(chunk.n))
+            iters += int(c.n_iters)
+            expanded += int(c.n_expanded)
+            if c.overflow:
                 raise RuntimeError(
                     f"evaluator buffer overflow on partition {pid}; raise "
                     f"EngineConfig.cap (currently {cfg.cap})")
-            absorb_eval_outputs(st, pid, self.pg.k,
-                                res.comp_rows, int(res.comp_n),
-                                res.out_rows, res.out_step, res.out_dest,
-                                int(res.out_n))
+            with self.tracer.span("eval.absorb") as asp:
+                bufs = read_rows(res, c)
+                absorb_eval_outputs(st, pid, self.pg.k, bufs, int(c.comp_n),
+                                    int(c.out_n))
+                if self.tracer.enabled:
+                    asp.set(bytes_d2h=host_nbytes(bufs))
+        return iters, expanded
 
     def run(self, plan: Plan, heuristic: str, seed: int = 0,
             max_loads: Optional[int] = None,
@@ -186,19 +174,23 @@ class OPATEngine:
                                 track_answer_keys=max_answers is not None)
         limit = max_loads if max_loads is not None else 64 * self.pg.k
         load0 = self.store.stats.copy()
+        iters = expanded = 0
 
         while not st.budget_met(max_answers):
-            eligible = st.eligible()
+            with self.tracer.span("heuristics.rank") as rsp:
+                eligible = st.eligible()
+                rsp.set(n_eligible=len(eligible))
+                if eligible:
+                    sni = {p: st.sni_count(p) for p in eligible}
+                    rates = (st.completion_rates() if heuristic == MAX_YIELD
+                             else None)
+                    ranked = rank_partitions(heuristic, eligible, sni, rng,
+                                             rates, tracer=self.tracer)
             if not eligible:
                 break
             if len(st.loads) >= limit:
                 raise RuntimeError("OPAT exceeded max partition loads "
                                    f"({limit}); likely a routing bug")
-            sni = {p: st.sni_count(p) for p in eligible}
-            rates = (st.completion_rates() if heuristic == MAX_YIELD
-                     else None)
-            ranked = rank_partitions(heuristic, eligible, sni, rng, rates,
-                                     tracer=self.tracer)
             pid = ranked[0]
             with self.tracer.span("opat.round", pid=pid,
                                   iteration=st.iterations,
@@ -219,8 +211,11 @@ class OPATEngine:
                 with self.store.pinned(pid):
                     if self.prefetch and len(ranked) > 1:
                         self.store.prefetch(ranked[1])
-                    self._run_partition(entry, plan_arrays, plan.n_steps,
-                                        batch, seed_fresh, st)
+                    it, nx = self._run_partition(entry, plan_arrays,
+                                                 plan.n_steps, batch,
+                                                 seed_fresh, st)
+                iters += it
+                expanded += nx
 
         answers = truncate_answers(st.unique_answers(), max_answers)
         delta = self.store.stats - load0
@@ -231,15 +226,8 @@ class OPATEngine:
                          n_answers=int(answers.shape[0]),
                          iterations=st.iterations,
                          answers_requested=max_answers,
-                         cold_loads=delta.cold_loads,
-                         warm_loads=delta.warm_loads,
-                         prefetch_hits=delta.prefetch_hits,
-                         disk_reads=delta.disk_reads,
-                         read_ahead_hits=delta.read_ahead_hits,
-                         bytes_cold=delta.bytes_cold,
-                         bytes_prefetched=delta.bytes_prefetched,
-                         bytes_disk=delta.bytes_disk,
-                         bytes_host=delta.bytes_host)
+                         eval_iters=iters, rows_expanded=expanded,
+                         **residency(delta))
         return OPATResult(answers=answers, stats=stats, state=st)
 
     def run_request(self, req: RunRequest) -> RunReport:

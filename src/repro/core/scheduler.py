@@ -69,7 +69,8 @@ import numpy as np
 
 from .heuristics import MAX_YIELD_SHARED, SHARED_HEURISTICS, \
     rank_partitions_shared
-from .metrics import RunStats, l_ideal_for_plan
+from .engine import host_nbytes, read_rows, traced_eval
+from .metrics import RunStats, l_ideal_for_plan, residency
 from .opat import OPATEngine, absorb_eval_outputs
 from .plan import Plan, PlanArrays, generate_plan
 from .query import DisjunctiveQuery, Query
@@ -104,6 +105,8 @@ class _Job:
                                          # (the fairness aging signal)
     urgency: float = 0.0                 # deadline pressure (SLO front end:
                                          # slack-weighted; 0 = no deadline)
+    eval_iters: int = 0                  # evaluator trips of the lanes this
+    rows_expanded: int = 0               # job rode, and rows they expanded
 
 
 @dataclasses.dataclass
@@ -207,9 +210,6 @@ class QueryScheduler:
         self._next_qid = 0
         self._jobs: List[_Job] = []
         self._touched: Set[int] = set()   # pids the shared loop ever loaded
-        # batch buckets whose vmapped evaluator trace already compiled —
-        # the first call per bucket gets a "kernel.compile" child span
-        self._traced_buckets: Set[int] = set()
         self.loads: List[int] = []
         self.batch_sizes: List[int] = []
 
@@ -237,7 +237,8 @@ class QueryScheduler:
             # plans and SNI counts come from the scheduler's PINNED
             # binding, not the session's live one — one scheduler, one
             # generation, even for queries admitted after a mutation
-            plan = generate_plan(q, self._graph, self._catalog)
+            with self.tracer.span("query.plan", query=q.name):
+                plan = generate_plan(q, self._graph, self._catalog)
             assert plan.n_slots <= cfg.q_pad and plan.n_steps <= cfg.s_pad
             counts = self.pg.start_label_counts(plan.start_label,
                                                 plan.start_value_op,
@@ -349,33 +350,12 @@ class QueryScheduler:
         while True:
             if max_rounds is not None and rounds >= max_rounds:
                 break
-            self._retire()
-            waiters = self._waiters()
+            waiters, ranked = self._rank(rng)
             if not waiters:
                 break
             if len(self.loads) >= limit:
                 raise RuntimeError("scheduler exceeded max partition loads "
                                    f"({limit}); likely a routing bug")
-            # score each candidate by every waiter's (SNI, completion
-            # rate); a job's rates are partition-indexed but identical
-            # across candidates, so compute them once per job per round —
-            # and only when the ranking reads them (as in the per-query
-            # OPAT loop, which gates rates on MAX-YIELD the same way)
-            rates = {}
-            if self.heuristic == MAX_YIELD_SHARED:
-                for js in waiters.values():
-                    for j in js:
-                        if id(j) not in rates:
-                            rates[id(j)] = j.state.completion_rates()
-            scored = {p: [(j.state.sni_count(p),
-                           rates[id(j)][p] if rates else 0.0,
-                           j.rounds_waiting,
-                           j.urgency)
-                          for j in js]
-                      for p, js in waiters.items()}
-            ranked = rank_partitions_shared(
-                self.heuristic, scored, rng,
-                fairness_gamma=self.fairness_gamma, tracer=self.tracer)
             pid = int(ranked[0])
             batch = waiters[pid]
             with self.tracer.span("scheduler.round", pid=pid, round=rounds,
@@ -421,6 +401,38 @@ class QueryScheduler:
                         else j.rounds_waiting + 1
             rounds += 1
 
+    def _rank(self, rng: np.random.Generator):
+        """Retire finished jobs, index the waiters by partition and rank
+        the candidates (one ``heuristics.rank`` span); returns the
+        waiters and the ranked pids (empty when nothing is eligible)."""
+        with self.tracer.span("heuristics.rank") as rsp:
+            self._retire()
+            waiters = self._waiters()
+            rsp.set(n_eligible=len(waiters))
+            if not waiters:
+                return waiters, []
+            # score each candidate by every waiter's (SNI, completion
+            # rate); a job's rates are partition-indexed but identical
+            # across candidates, so compute them once per job per round —
+            # and only when the ranking reads them (as in the per-query
+            # OPAT loop, which gates rates on MAX-YIELD the same way)
+            rates = {}
+            if self.heuristic == MAX_YIELD_SHARED:
+                for js in waiters.values():
+                    for j in js:
+                        if id(j) not in rates:
+                            rates[id(j)] = j.state.completion_rates()
+            scored = {p: [(j.state.sni_count(p),
+                           rates[id(j)][p] if rates else 0.0,
+                           j.rounds_waiting,
+                           j.urgency)
+                          for j in js]
+                      for p, js in waiters.items()}
+            ranked = rank_partitions_shared(
+                self.heuristic, scored, rng,
+                fairness_gamma=self.fairness_gamma, tracer=self.tracer)
+        return waiters, ranked
+
     def _run_shared_tmp(self, t0: float,
                         max_rounds: Optional[int] = None) -> None:
         """TraditionalMP shared batching: each round ranks partitions with
@@ -443,28 +455,12 @@ class QueryScheduler:
         while True:
             if max_rounds is not None and rounds >= max_rounds:
                 break
-            self._retire()
-            waiters = self._waiters()
+            waiters, ranked = self._rank(rng)
             if not waiters:
                 break
             if len(self.loads) >= limit:
                 raise RuntimeError("scheduler exceeded max partition loads "
                                    f"({limit}); likely a routing bug")
-            rates = {}
-            if self.heuristic == MAX_YIELD_SHARED:
-                for js in waiters.values():
-                    for j in js:
-                        if id(j) not in rates:
-                            rates[id(j)] = j.state.completion_rates()
-            scored = {pp: [(j.state.sni_count(pp),
-                            rates[id(j)][pp] if rates else 0.0,
-                            j.rounds_waiting,
-                            j.urgency)
-                           for j in js]
-                      for pp, js in waiters.items()}
-            ranked = rank_partitions_shared(
-                self.heuristic, scored, rng,
-                fairness_gamma=self.fairness_gamma, tracer=self.tracer)
             # canonical sorted order + first-pid padding, exactly as the
             # per-query TMP loop: the stacked store key is then
             # permutation-invariant across rounds (padding lanes are
@@ -484,79 +480,74 @@ class QueryScheduler:
                      if not j.retired and id(j) in in_round]
             B = len(batch)
             Bpad = batch_bucket(B)
-            plans = [j.plan_arrays for j in batch]
-            stacked = PlanArrays.stack(plans + [plans[0]] * (Bpad - B))
-            n_steps = np.asarray([j.plan.n_steps for j in batch]
-                                 + [1] * (Bpad - B), np.int32)
-            in_rows = np.full((Bpad, p, cfg.cap, cfg.q_pad), -1, np.int32)
-            in_step = np.zeros((Bpad, p, cfg.cap), np.int32)
-            in_valid = np.zeros((Bpad, p, cfg.cap), bool)
-            seeds = np.zeros((Bpad, p), bool)
             lanes_of: List[List[int]] = []   # per job: real lanes it rode
-            for b, j in enumerate(batch):
-                mine: List[int] = []
-                for i, pid in enumerate(exec_set):
-                    if not is_real[i] or id(j) not in waiter_ids[pid]:
-                        continue
-                    mine.append(i)
-                    bb = j.state.ima[pid]
-                    j.state.ima[pid] = BindingBatch.empty(cfg.q_pad)
-                    if bb.n > cfg.cap:
-                        # tail kept for a later round of the same partition
-                        j.state.ima[pid] = BindingBatch(
-                            rows=bb.rows[cfg.cap:], step=bb.step[cfg.cap:])
-                        bb = BindingBatch(rows=bb.rows[: cfg.cap],
-                                          step=bb.step[: cfg.cap])
-                    if bb.n:
-                        in_rows[b, i, : bb.n] = bb.rows
-                        in_step[b, i, : bb.n] = bb.step
-                        in_valid[b, i, : bb.n] = True
-                    seeds[b, i] = bool(j.state.fresh_pending[pid])
-                    j.state.fresh_pending[pid] = False
-                lanes_of.append(mine)
+            with self.tracer.span("eval.inputs") as isp:
+                plans = [j.plan_arrays for j in batch]
+                stacked = PlanArrays.stack(plans + [plans[0]] * (Bpad - B))
+                n_steps = np.asarray([j.plan.n_steps for j in batch]
+                                     + [1] * (Bpad - B), np.int32)
+                in_rows = np.full((Bpad, p, cfg.cap, cfg.q_pad), -1, np.int32)
+                in_step = np.zeros((Bpad, p, cfg.cap), np.int32)
+                in_valid = np.zeros((Bpad, p, cfg.cap), bool)
+                seeds = np.zeros((Bpad, p), bool)
+                for b, j in enumerate(batch):
+                    mine: List[int] = []
+                    for i, pid in enumerate(exec_set):
+                        if not is_real[i] or id(j) not in waiter_ids[pid]:
+                            continue
+                        mine.append(i)
+                        bb = j.state.ima[pid]
+                        j.state.ima[pid] = BindingBatch.empty(cfg.q_pad)
+                        if bb.n > cfg.cap:
+                            # tail kept for a later round of the same
+                            # partition
+                            j.state.ima[pid] = BindingBatch(
+                                rows=bb.rows[cfg.cap:],
+                                step=bb.step[cfg.cap:])
+                            bb = BindingBatch(rows=bb.rows[: cfg.cap],
+                                              step=bb.step[: cfg.cap])
+                        if bb.n:
+                            in_rows[b, i, : bb.n] = bb.rows
+                            in_step[b, i, : bb.n] = bb.step
+                            in_valid[b, i, : bb.n] = True
+                        seeds[b, i] = bool(j.state.fresh_pending[pid])
+                        j.state.fresh_pending[pid] = False
+                    lanes_of.append(mine)
+                plan_args = (stacked, n_steps, in_rows, in_step, in_valid,
+                             seeds)
+                n_rows = int(in_valid.sum())
+                isp.set(rows=n_rows)
+                if self.tracer.enabled:
+                    isp.set(bytes_h2d=host_nbytes(plan_args))
             ev0 = self.store.stats.copy()
             with self.tracer.span("scheduler.round", pids=chosen,
                                   round=rounds, batch=B,
                                   qids=sorted({j.qid for j in batch})):
                 entry = self.store.get_stacked(tuple(exec_set))
                 event = self.store.stats - ev0
-                with self.tracer.span("kernel.eval", pids=chosen, batch=B,
-                                      bucket=Bpad) as ksp:
-                    if -Bpad not in self._traced_buckets:
-                        # negative keys: the TMP double-vmap's jit cache is
-                        # separate from the OPAT batched evaluator's
-                        self._traced_buckets.add(-Bpad)
-                        ksp.set(first_call=True)
-                        self.profiler.attribute_kernel(
-                            ("scheduler.tmp", Bpad), seval, entry.part,
-                            entry.g2l, self.store.owner, stacked, n_steps,
-                            in_rows, in_step, in_valid, seeds)
-                        with self.tracer.span("kernel.compile", bucket=Bpad):
-                            res = seval(entry.part, entry.g2l,
-                                        self.store.owner, stacked, n_steps,
-                                        in_rows, in_step, in_valid, seeds)
-                    else:
-                        res = seval(entry.part, entry.g2l, self.store.owner,
-                                    stacked, n_steps, in_rows, in_step,
-                                    in_valid, seeds)
-                    overflow = np.asarray(res.overflow)
-                    self.profiler.stamp_kernel(ksp, ("scheduler.tmp", Bpad))
-                    self.profiler.sample_device(ksp, self.store)
-            comp_rows, comp_n = np.asarray(res.comp_rows), np.asarray(res.comp_n)
-            out_rows, out_n = np.asarray(res.out_rows), np.asarray(res.out_n)
-            out_step, out_dest = np.asarray(res.out_step), np.asarray(res.out_dest)
+                res, c = traced_eval(
+                    self, ("scheduler.tmp", Bpad), seval,
+                    (entry.part, entry.g2l, self.store.owner) + plan_args,
+                    pids=chosen, batch=B, bucket=Bpad, rows=n_rows)
             for b, j in enumerate(batch):
                 for i in lanes_of[b]:
-                    if bool(overflow[b, i]):
+                    if bool(c.overflow[b, i]):
                         raise RuntimeError(
                             f"evaluator buffer overflow on partition "
                             f"{exec_set[i]} (query {j.plan.query.name!r} in "
                             f"a batch of {B}); raise EngineConfig.cap "
                             f"(currently {cfg.cap})")
-                    absorb_eval_outputs(j.state, exec_set[i], k,
-                                        comp_rows[b, i], int(comp_n[b, i]),
-                                        out_rows[b, i], out_step[b, i],
-                                        out_dest[b, i], int(out_n[b, i]))
+            with self.tracer.span("eval.absorb") as asp:
+                bufs = read_rows(res, c)
+                for b, j in enumerate(batch):
+                    for i in lanes_of[b]:
+                        absorb_eval_outputs(j.state, exec_set[i], k, bufs,
+                                            int(c.comp_n[b, i]),
+                                            int(c.out_n[b, i]), lane=(b, i))
+                        j.eval_iters += int(c.n_iters[b, i])
+                        j.rows_expanded += int(c.n_expanded[b, i])
+                if self.tracer.enabled:
+                    asp.set(bytes_d2h=host_nbytes(bufs))
             # attribution: the stacked bundle is ONE store event; each
             # chosen pid counts one workload load, and its batch size is
             # the number of jobs its lane advanced
@@ -588,10 +579,6 @@ class QueryScheduler:
         k = self.pg.k
         B = len(batch)
         Bpad = batch_bucket(B)
-        plans = [j.plan_arrays for j in batch]
-        stacked = PlanArrays.stack(plans + [plans[0]] * (Bpad - B))
-        n_steps = np.asarray([j.plan.n_steps for j in batch]
-                             + [1] * (Bpad - B), np.int32)
         imas: List[BindingBatch] = []
         seed_flags: List[bool] = []
         for j in batch:
@@ -601,51 +588,50 @@ class QueryScheduler:
             j.state.fresh_pending[pid] = False
         n_chunks = max(1, max(-(-bb.n // cfg.cap) for bb in imas))
         for ci in range(n_chunks):
-            in_rows = np.full((Bpad, cfg.cap, cfg.q_pad), -1, np.int32)
-            in_step = np.zeros((Bpad, cfg.cap), np.int32)
-            in_valid = np.zeros((Bpad, cfg.cap), bool)
-            for b, bb in enumerate(imas):
-                lo = ci * cfg.cap
-                n = min(bb.n - lo, cfg.cap)
-                if n > 0:
-                    in_rows[b, :n] = bb.rows[lo:lo + n]
-                    in_step[b, :n] = bb.step[lo:lo + n]
-                    in_valid[b, :n] = True
-            sf = np.asarray([s and ci == 0 for s in seed_flags]
-                            + [False] * (Bpad - B))
-            with self.tracer.span("kernel.eval", pid=pid, batch=B,
-                                  bucket=Bpad) as ksp:
-                if Bpad not in self._traced_buckets:
-                    self._traced_buckets.add(Bpad)
-                    ksp.set(first_call=True)
-                    self.profiler.attribute_kernel(
-                        ("scheduler.opat", Bpad), beval, entry.part,
-                        entry.g2l, self.store.owner, stacked, n_steps,
-                        in_rows, in_step, in_valid, sf)
-                    with self.tracer.span("kernel.compile", bucket=Bpad):
-                        res = beval(entry.part, entry.g2l, self.store.owner,
-                                    stacked, n_steps, in_rows, in_step,
-                                    in_valid, sf)
-                else:
-                    res = beval(entry.part, entry.g2l, self.store.owner,
-                                stacked, n_steps, in_rows, in_step,
-                                in_valid, sf)
-                overflow = np.asarray(res.overflow)
-                self.profiler.stamp_kernel(ksp, ("scheduler.opat", Bpad))
-                self.profiler.sample_device(ksp, self.store)
-            comp_rows, comp_n = np.asarray(res.comp_rows), np.asarray(res.comp_n)
-            out_rows, out_n = np.asarray(res.out_rows), np.asarray(res.out_n)
-            out_step, out_dest = np.asarray(res.out_step), np.asarray(res.out_dest)
+            with self.tracer.span("eval.inputs") as isp:
+                if ci == 0:
+                    plans = [j.plan_arrays for j in batch]
+                    stacked = PlanArrays.stack(plans
+                                               + [plans[0]] * (Bpad - B))
+                    n_steps = np.asarray([j.plan.n_steps for j in batch]
+                                         + [1] * (Bpad - B), np.int32)
+                in_rows = np.full((Bpad, cfg.cap, cfg.q_pad), -1, np.int32)
+                in_step = np.zeros((Bpad, cfg.cap), np.int32)
+                in_valid = np.zeros((Bpad, cfg.cap), bool)
+                n_rows = 0
+                for b, bb in enumerate(imas):
+                    lo = ci * cfg.cap
+                    n = min(bb.n - lo, cfg.cap)
+                    if n > 0:
+                        in_rows[b, :n] = bb.rows[lo:lo + n]
+                        in_step[b, :n] = bb.step[lo:lo + n]
+                        in_valid[b, :n] = True
+                        n_rows += n
+                sf = np.asarray([s and ci == 0 for s in seed_flags]
+                                + [False] * (Bpad - B))
+                args = (entry.part, entry.g2l, self.store.owner, stacked,
+                        n_steps, in_rows, in_step, in_valid, sf)
+                isp.set(rows=n_rows)
+                if self.tracer.enabled:
+                    isp.set(bytes_h2d=host_nbytes(args))
+            res, c = traced_eval(self, ("scheduler.opat", Bpad), beval, args,
+                                 pid=pid, batch=B, bucket=Bpad, rows=n_rows)
             for b, j in enumerate(batch):
-                if bool(overflow[b]):
+                if bool(c.overflow[b]):
                     raise RuntimeError(
                         f"evaluator buffer overflow on partition {pid} "
                         f"(query {j.plan.query.name!r} in a batch of {B}); "
                         f"raise EngineConfig.cap (currently {cfg.cap})")
-                absorb_eval_outputs(j.state, pid, k,
-                                    comp_rows[b], int(comp_n[b]),
-                                    out_rows[b], out_step[b], out_dest[b],
-                                    int(out_n[b]))
+            with self.tracer.span("eval.absorb") as asp:
+                bufs = read_rows(res, c)
+                for b, j in enumerate(batch):
+                    absorb_eval_outputs(j.state, pid, k, bufs,
+                                        int(c.comp_n[b]), int(c.out_n[b]),
+                                        lane=(b,))
+                    j.eval_iters += int(c.n_iters[b])
+                    j.rows_expanded += int(c.n_expanded[b])
+                if self.tracer.enabled:
+                    asp.set(bytes_d2h=host_nbytes(bufs))
 
     def _run_sequential(self, t0: float,
                         max_rounds: Optional[int] = None) -> None:
@@ -758,15 +744,9 @@ class QueryScheduler:
                             n_answers=int(a.shape[0]),
                             iterations=j.state.iterations,
                             answers_requested=j.max_answers,
-                            cold_loads=delta.cold_loads,
-                            warm_loads=delta.warm_loads,
-                            prefetch_hits=delta.prefetch_hits,
-                            disk_reads=delta.disk_reads,
-                            read_ahead_hits=delta.read_ahead_hits,
-                            bytes_cold=delta.bytes_cold,
-                            bytes_prefetched=delta.bytes_prefetched,
-                            bytes_disk=delta.bytes_disk,
-                            bytes_host=delta.bytes_host),
+                            eval_iters=j.eval_iters,
+                            rows_expanded=j.rows_expanded,
+                            **residency(delta)),
                         engine=self.session.engine_name,
                         extra={"state": j.state})
                 rep.stats.generation = gen

@@ -291,7 +291,8 @@ class GraphSession:
                               engine=self.engine_name,
                               generation=gen) as qsp, ctx:
             for q in disjuncts:
-                plan = generate_plan(q, self.graph, self.catalog)
+                with self.tracer.span("query.plan", query=q.name):
+                    plan = generate_plan(q, self.graph, self.catalog)
                 rep = self.engine.run_request(RunRequest(
                     plan=plan, heuristic=h, max_answers=max_answers, seed=s))
                 reports.append(rep)

@@ -18,10 +18,12 @@ from typing import List, Optional
 import jax
 import numpy as np
 
-from .engine import EngineConfig, make_partition_evaluator
+from .engine import (EngineConfig, host_nbytes, jit_evaluator,
+                     make_partition_evaluator, read_rows, traced_eval)
 from .graph import PartitionedGraph
 from .heuristics import MAX_YIELD, choose_top_p
-from .metrics import RunStats, l_ideal_for_plan
+from .metrics import RunStats, l_ideal_for_plan, residency
+from .opat import absorb_eval_outputs
 from .plan import Plan, PlanArrays
 from .runner import RunReport, RunRequest, truncate_answers
 from .state import BindingBatch, QueryState
@@ -53,7 +55,7 @@ class TraditionalMPEngine:
         self._eval = make_partition_evaluator(pg.node_pad, pg.ell_width,
                                               self.cfg)
         # vmapped over (partition arrays, g2l row, inputs); plan broadcast
-        self._veval = jax.jit(jax.vmap(
+        self._veval = jit_evaluator(jax.vmap(
             self._eval, in_axes=(0, 0, None, None, None, 0, 0, 0, 0)))
         self._seval = None       # lazy: the queries x partitions double-vmap
         self.store = store if store is not None else PartitionStore(pg)
@@ -61,7 +63,6 @@ class TraditionalMPEngine:
         self.tracer = tracer if tracer is not None else NULL_TRACER
         from ..obs.profile import NULL_PROFILER
         self.profiler = profiler if profiler is not None else NULL_PROFILER
-        self._eval_traced = False
 
     def shared_evaluator(self):
         """The *stacked top-p, multi-query* evaluator: ``vmap`` over the
@@ -75,7 +76,7 @@ class TraditionalMPEngine:
         the whole workload, not one query.  Built lazily — per-query
         serving never pays the extra trace."""
         if self._seval is None:
-            self._seval = jax.jit(jax.vmap(
+            self._seval = jit_evaluator(jax.vmap(
                 jax.vmap(self._eval,
                          in_axes=(0, 0, None, None, None, 0, 0, 0, 0)),
                 in_axes=(None, None, None, 0, 0, 0, 0, 0, 0)))
@@ -96,20 +97,24 @@ class TraditionalMPEngine:
         limit = max_iterations if max_iterations is not None else 64 * self.pg.k
         per_iter: List[List[int]] = []
         load0 = self.store.stats.copy()
+        iters = expanded = 0
 
         # budget check after each top-p merge (and before the first load:
         # a K=0 request does no work)
         while not st.budget_met(max_answers):
-            eligible = st.eligible()
+            with self.tracer.span("heuristics.rank") as rsp:
+                eligible = st.eligible()
+                rsp.set(n_eligible=len(eligible))
+                if eligible:
+                    sni = {p: st.sni_count(p) for p in eligible}
+                    rates = (st.completion_rates()
+                             if heuristic == MAX_YIELD else None)
+                    chosen = choose_top_p(heuristic, eligible, sni, self.p,
+                                          rng, rates, tracer=self.tracer)
             if not eligible:
                 break
             if st.iterations >= limit:
                 raise RuntimeError("TraditionalMP exceeded max iterations")
-            sni = {p: st.sni_count(p) for p in eligible}
-            rates = (st.completion_rates() if heuristic == MAX_YIELD
-                     else None)
-            chosen = choose_top_p(heuristic, eligible, sni, self.p, rng,
-                                  rates, tracer=self.tracer)
             per_iter.append(list(chosen))
             st.iterations += 1
             # process the set in sorted order: which processor runs which
@@ -156,71 +161,45 @@ class TraditionalMPEngine:
             seeds = [t[2] for t in lanes]
             is_real = [t[3] for t in lanes]
 
-            n = self.p
-            in_rows = np.full((n, cfg.cap, cfg.q_pad), -1, dtype=np.int32)
-            in_step = np.zeros((n, cfg.cap), dtype=np.int32)
-            in_valid = np.zeros((n, cfg.cap), dtype=bool)
-            for i, b in enumerate(batches):
-                if b.n:
-                    in_rows[i, : b.n] = b.rows
-                    in_step[i, : b.n] = b.step
-                    in_valid[i, : b.n] = True
+            with self.tracer.span("eval.inputs",
+                                  rows=sum(b.n for b in batches)) as isp:
+                in_rows = np.full((self.p, cfg.cap, cfg.q_pad), -1,
+                                  dtype=np.int32)
+                in_step = np.zeros((self.p, cfg.cap), dtype=np.int32)
+                in_valid = np.zeros((self.p, cfg.cap), dtype=bool)
+                for i, b in enumerate(batches):
+                    if b.n:
+                        in_rows[i, : b.n] = b.rows
+                        in_step[i, : b.n] = b.step
+                        in_valid[i, : b.n] = True
+                plan_args = (plan_arrays, np.int32(plan.n_steps), in_rows,
+                             in_step, in_valid, np.asarray(seeds, dtype=bool))
+                if self.tracer.enabled:
+                    isp.set(bytes_h2d=host_nbytes(plan_args))
 
             with self.tracer.span("engine.iteration", engine="traditional",
                                   pids=list(map(int, exec_set)),
                                   iteration=st.iterations):
                 entry = self.store.get_stacked(tuple(exec_set))
-                with self.tracer.span("kernel.eval", engine="traditional",
-                                      pids=list(map(int, exec_set))) as ksp:
-                    if not self._eval_traced:
-                        self._eval_traced = True
-                        ksp.set(first_call=True)
-                        self.profiler.attribute_kernel(
-                            ("traditional", "veval"), self._veval,
-                            entry.part, entry.g2l, self.store.owner,
-                            plan_arrays, np.int32(plan.n_steps),
-                            in_rows, in_step, in_valid,
-                            np.asarray(seeds, dtype=bool))
-                        with self.tracer.span("kernel.compile",
-                                              engine="traditional"):
-                            res = self._veval(entry.part, entry.g2l,
-                                              self.store.owner, plan_arrays,
-                                              np.int32(plan.n_steps),
-                                              in_rows, in_step, in_valid,
-                                              np.asarray(seeds, dtype=bool))
-                    else:
-                        res = self._veval(entry.part, entry.g2l,
-                                          self.store.owner, plan_arrays,
-                                          np.int32(plan.n_steps),
-                                          in_rows, in_step, in_valid,
-                                          np.asarray(seeds, dtype=bool))
-                    overflow = bool(np.any(np.asarray(res.overflow)))
-                    self.profiler.stamp_kernel(ksp, ("traditional", "veval"))
-                    self.profiler.sample_device(ksp, self.store)
-            if overflow:
+                res, c = traced_eval(
+                    self, ("traditional", "veval"), self._veval,
+                    (entry.part, entry.g2l, self.store.owner) + plan_args,
+                    engine="traditional", pids=list(map(int, exec_set)),
+                    rows=int(sum(b.n for b in batches)))
+            iters += int(np.sum(c.n_iters))
+            expanded += int(np.sum(c.n_expanded))
+            if np.any(c.overflow):
                 raise RuntimeError("evaluator buffer overflow; raise cap")
-            comp_rows = np.asarray(res.comp_rows)
-            comp_n = np.asarray(res.comp_n)
-            out_rows = np.asarray(res.out_rows)
-            out_step = np.asarray(res.out_step)
-            out_dest = np.asarray(res.out_dest)
-            out_n = np.asarray(res.out_n)
-            for i in range(n):  # merge IMA_i -> FAA/IMA (order-insensitive)
-                if not is_real[i]:
-                    continue
-                if comp_n[i]:
-                    st.add_answers(comp_rows[i, : comp_n[i]])
-                st.observe_yield(exec_set[i], int(comp_n[i]), int(out_n[i]))
-                if out_n[i]:
-                    orow = out_rows[i, : out_n[i]]
-                    ostp = out_step[i, : out_n[i]]
-                    odst = out_dest[i, : out_n[i]]
-                    for q in range(self.pg.k):
-                        sel = odst == q
-                        if sel.any():
-                            st.ima[q] = st.ima[q].concat(
-                                BindingBatch(rows=orow[sel], step=ostp[sel])
-                            ).dedup()
+            with self.tracer.span("eval.absorb") as asp:
+                bufs = read_rows(res, c)
+                # merge IMA_i -> FAA/IMA (order-insensitive)
+                for i in range(self.p):
+                    if is_real[i]:
+                        absorb_eval_outputs(st, exec_set[i], self.pg.k, bufs,
+                                            int(c.comp_n[i]), int(c.out_n[i]),
+                                            lane=(i,))
+                if self.tracer.enabled:
+                    asp.set(bytes_d2h=host_nbytes(bufs))
 
         answers = truncate_answers(st.unique_answers(), max_answers)
         delta = self.store.stats - load0
@@ -231,15 +210,8 @@ class TraditionalMPEngine:
                          n_answers=int(answers.shape[0]),
                          iterations=st.iterations,
                          answers_requested=max_answers,
-                         cold_loads=delta.cold_loads,
-                         warm_loads=delta.warm_loads,
-                         prefetch_hits=delta.prefetch_hits,
-                         disk_reads=delta.disk_reads,
-                         read_ahead_hits=delta.read_ahead_hits,
-                         bytes_cold=delta.bytes_cold,
-                         bytes_prefetched=delta.bytes_prefetched,
-                         bytes_disk=delta.bytes_disk,
-                         bytes_host=delta.bytes_host)
+                         eval_iters=iters, rows_expanded=expanded,
+                         **residency(delta))
         return TraditionalMPResult(answers=answers, stats=stats,
                                    state=st, partitions_per_iteration=per_iter)
 

@@ -553,14 +553,19 @@ def main() -> None:
         n_loads = res.n_loads
         l_ideal = max(s.l_ideal for s in res.stats)
         iters = max(s.iterations for s in res.stats)
+        eval_iters = sum(s.eval_iters for s in res.stats)
+        expanded = [s.rows_expanded for s in res.stats]
+        expanded = None if None in expanded else sum(expanded)
         ls = res.load_stats
         print(f"[serve] {dq.name}: answers={answers.shape[0]:5d} "
               f"loads={n_loads} (cold={ls.cold_loads} warm={ls.warm_loads} "
               f"pf_hits={ls.prefetch_hits}) L_ideal={l_ideal} iters={iters} "
+              f"eval_trips={eval_iters} rows_expanded={expanded} "
               f"latency={res.latency_s*1000:.0f} ms "
               f"load_seq={[s.loads for s in res.stats]}")
         rec = {"query": dq.name, "answers": int(answers.shape[0]),
                "loads": n_loads, "l_ideal": l_ideal, "iterations": iters,
+               "eval_iters": eval_iters, "rows_expanded": expanded,
                "latency_s": res.latency_s,
                "cold_loads": ls.cold_loads, "warm_loads": ls.warm_loads,
                "prefetch_hits": ls.prefetch_hits,

@@ -124,9 +124,9 @@ class ResourceProfiler:
     def attribute_kernel(self, key: Any, fn: Any, *args: Any) -> Dict[str, Any]:
         """Predicted cost of the compiled bucket ``key``: lower ``fn`` on
         ``args`` (abstract — no execution), analyze the HLO, fold through
-        the roofline.  Computed once per key; call sites invoke this from
-        the same first-call branch that owns the ``kernel.compile`` span,
-        so steady-state evals never pay for lowering."""
+        the roofline.  Computed once per key: every evaluator call passes
+        through here (``core/engine.traced_eval``), and all but a key's
+        first return the cached cost without lowering."""
         skey = _key_str(key)
         cached = self.kernel_costs.get(skey)
         if cached is not None:
@@ -163,9 +163,8 @@ class ResourceProfiler:
 
     def stamp_kernel(self, span: Any, key: Any) -> None:
         """Write the bucket's predicted cost onto a ``kernel.eval`` span
-        (no-op until ``attribute_kernel`` ran for the key — i.e. before
-        the first call compiled the bucket, which cannot happen since the
-        first call attributes before it evaluates)."""
+        (no-op until ``attribute_kernel`` ran for the key, which every
+        call does before it evaluates)."""
         c = self.kernel_costs.get(_key_str(key))
         if c is None:
             return

@@ -28,6 +28,22 @@ The ON state (``Tracer``) records:
 Appends take a lock (read-ahead threads trace too); span-stack state is
 thread-local.  All timestamps share one ``perf_counter`` timebase, so
 spans from different threads order correctly in the exported trace.
+
+Two bridges to JAX, both set up lazily so this module imports without it:
+
+  annotations — every context-manager span of a ``Tracer`` also enters
+              ``jax.profiler.TraceAnnotation(name)``, so
+              a ``jax.profiler`` trace holds a same-named twin of each
+              span on its host plane, on the device events' clock.
+              ``add_span``/``event`` record externally captured
+              timestamps and have no twin.
+  compiles  — every XLA compile in the process (JAX's
+              ``backend_compile_duration`` monitoring event, persistent
+              cache reads included) becomes a ``jit.compile`` span over
+              ``[now - secs, now]``, parented under the compiling
+              thread's innermost open span.  A compile lands in the live
+              tracer whose open span on that thread started last; one
+              outside every open span is recorded nowhere.
 """
 from __future__ import annotations
 
@@ -35,7 +51,10 @@ import dataclasses
 import itertools
 import threading
 import time
+import weakref
 from typing import Any, Dict, List, Optional
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 @dataclasses.dataclass
@@ -107,15 +126,23 @@ NULL_TRACER = NullTracer()
 
 class _SpanCtx:
     """Context manager for one live span: pushes onto the calling
-    thread's stack on enter, stamps ``t1`` and records on exit."""
+    thread's stack on enter, stamps ``t1`` and records on exit.  Its
+    profiler twin (if annotating) opens before ``t0`` and closes after
+    ``t1``."""
 
-    __slots__ = ("_tracer", "_span")
+    __slots__ = ("_tracer", "_span", "_twin")
 
     def __init__(self, tracer: "Tracer", span: Span):
         self._tracer = tracer
         self._span = span
+        self._twin = None
 
     def __enter__(self) -> Span:
+        annotation = self._tracer._annotation
+        if annotation is not None:
+            self._twin = annotation(self._span.name)
+            self._twin.__enter__()
+        self._span.t0 = time.perf_counter()
         self._tracer._push(self._span)
         return self._span
 
@@ -125,7 +152,44 @@ class _SpanCtx:
         if exc_type is not None:
             sp.attrs.setdefault("error", exc_type.__name__)
         self._tracer._pop(sp)
+        if self._twin is not None:
+            self._twin.__exit__(None, None, None)
         return False
+
+
+# live tracers that record compiles; the one JAX listener picks among them
+_COMPILE_TRACERS: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_listening = False
+
+
+def _on_compile(event: str, secs: float, **kw: Any) -> None:
+    """JAX monitoring listener: one ``jit.compile`` span per backend
+    compile, in the tracer whose innermost open span on this thread
+    started last."""
+    if event != COMPILE_EVENT:
+        return
+    now = time.perf_counter()
+    owner, parent = None, None
+    for tr in list(_COMPILE_TRACERS):
+        stack = getattr(tr._local, "stack", None)
+        if stack and (parent is None or stack[-1].t0 > parent.t0):
+            owner, parent = tr, stack[-1]
+    if owner is not None:
+        owner.add_span("jit.compile", now - secs, now,
+                       parent_id=parent.span_id, secs=float(secs),
+                       module=kw.get("fun_name"))
+
+
+def _listen_for_compiles(tracer: "Tracer") -> None:
+    global _listening
+    if not _listening:
+        try:
+            import jax.monitoring
+        except ImportError:
+            return
+        jax.monitoring.register_event_duration_secs_listener(_on_compile)
+        _listening = True
+    _COMPILE_TRACERS.add(tracer)
 
 
 class Tracer:
@@ -134,7 +198,8 @@ class Tracer:
     One tracer serves one session (and everything threaded under it —
     store, engines, scheduler, front end, delta layer).  Thread-safe:
     each thread nests spans on its own stack; the recorded lists are
-    append-only under a lock.
+    append-only under a lock.  Each context span is mirrored as a
+    ``jax.profiler.TraceAnnotation`` (not where JAX is missing).
     """
 
     enabled = True
@@ -147,13 +212,19 @@ class Tracer:
         self._local = threading.local()
         # the trace's epoch: exporters emit timestamps relative to this
         self.t_epoch = time.perf_counter()
+        try:
+            from jax.profiler import TraceAnnotation
+            self._annotation = TraceAnnotation
+        except ImportError:
+            self._annotation = None
+        _listen_for_compiles(self)
 
     # -- recording ----------------------------------------------------------
 
     def span(self, name: str, **attrs: Any) -> _SpanCtx:
         """``with tracer.span("store.load", pid=3) as sp: ...`` — records
         the block as one span, parented under the thread's innermost
-        open span."""
+        open span (``t0`` is stamped on entering the block)."""
         sp = Span(name=name, span_id=next(self._ids),
                   parent_id=self.current_span_id,
                   t0=time.perf_counter(), attrs=dict(attrs),
@@ -163,7 +234,8 @@ class Tracer:
     def add_span(self, name: str, t0: float, t1: float,
                  parent_id: Optional[int] = None, **attrs: Any) -> Span:
         """Record a span from timestamps the caller captured itself
-        (``time.perf_counter()`` seconds, same timebase as ``span``)."""
+        (``time.perf_counter()`` seconds, same timebase as ``span``).
+        Externally timed, so it has no profiler twin."""
         sp = Span(name=name, span_id=next(self._ids), parent_id=parent_id,
                   t0=float(t0), t1=float(t1), attrs=dict(attrs),
                   thread=threading.current_thread().name)
@@ -183,7 +255,8 @@ class Tracer:
             self._decisions.append(rec)
 
     def event(self, name: str, **attrs: Any) -> None:
-        """A zero-duration marker (exported as an instant event)."""
+        """A zero-duration marker (exported as an instant event; no
+        profiler twin)."""
         t = time.perf_counter()
         self.add_span(name, t, t, parent_id=self.current_span_id, **attrs)
 

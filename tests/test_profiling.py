@@ -470,6 +470,15 @@ def test_serve_json_schema_v3_and_cost_report(tmp_path):
     assert prof["peak_device_bytes"] > 0
     assert prof["kernel_costs"]["opat:eval"]["flops"] > 0
     assert prof["bytes"]["cold"] > 0
+    # each query's record carries the evaluator's work, the totals of
+    # the counters its kernel.eval spans were stamped with
+    doc = json.loads(trace.read_text())
+    kernel = [e["args"] for e in doc["traceEvents"]
+              if e.get("ph") == "X" and e.get("name") == "kernel.eval"]
+    for field, attr in (("eval_iters", "n_iters"),
+                        ("rows_expanded", "n_expanded")):
+        assert sum(q[field] for q in rep["queries"]) == \
+            sum(k[attr] for k in kernel) > 0
     # the cost table joins measured time with the prediction
     cost = subprocess.run(
         [sys.executable, "tools/trace_report.py", str(trace), "--cost"],

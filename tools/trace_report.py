@@ -8,8 +8,8 @@ can't answer:
   "what dominated latency?"  — every query root span is decomposed into
       the *self time* of its descendant spans (a child's duration minus
       its own children's durations), grouped by span name, so nested
-      spans (kernel.compile inside kernel.eval inside opat.round) are
-      never double-counted.  Store loads split by tier
+      spans (jit.compile inside eval.launch inside kernel.eval inside
+      opat.round) are never double-counted.  Store loads split by tier
       (cold/warm/prefetch).
 
   "why was P3 loaded before P1?" — heuristic decision records carry the
@@ -25,9 +25,10 @@ can't answer:
       from the bucket's lowered HLO plus the roofline-bound time);
       ``--cost`` joins that prediction with the measured steady-state
       wall time per compiled bucket: achieved FLOP/s, bound-vs-measured
-      ratio (% of roofline), and the live-device-byte watermark.  The
-      first call of each bucket (jit trace + compile) is excluded from
-      the steady-state mean.
+      ratio (% of roofline), and the live-device-byte watermark.  A
+      ``kernel.eval`` with a ``jit.compile`` descendant (the call that
+      compiled, whichever it was) is excluded from the steady-state
+      mean.
 
 Modes:
     python tools/trace_report.py trace.json            # full report
@@ -38,9 +39,10 @@ Modes:
 ``--check`` exits non-zero unless the trace is non-empty, every span
 nests inside its recorded parent, every query root span is closed
 (non-zero duration once it has children), every recorded heuristic
-choice is score-consistent, and cost attribution is all-or-none: if any
-``kernel.eval`` span carries cost attrs, every one must (a partially
-attributed trace means a kernel call site skipped the profiler).
+choice is score-consistent, and ``kernel.eval`` attrs are all-or-none:
+if any span carries cost attrs, or one of the evaluator's counters
+(``n_iters``, ``n_expanded``), every one must (a partially stamped trace
+means a kernel call site bypassed ``core/engine.traced_eval``).
 """
 from __future__ import annotations
 
@@ -263,10 +265,25 @@ def _kernel_spans(spans):
     return [sp for sp in spans if sp["name"] == "kernel.eval"]
 
 
+def compiled_span_ids(spans) -> set:
+    """Ids of the spans with a ``jit.compile`` descendant."""
+    by_id, _ = index_spans(spans)
+    out = set()
+    for sp in spans:
+        if sp["name"] != "jit.compile":
+            continue
+        pid = sp.get("args", {}).get("parent_id")
+        while pid is not None and pid not in out:
+            out.add(pid)
+            pid = by_id.get(pid, {}).get("args", {}).get("parent_id")
+    return out
+
+
 def report_cost(spans) -> None:
     """Per-compiled-bucket cost attribution: measured steady-state wall
     time joined with the predicted FLOPs/bytes/roofline bound the
     profiler stamped on every ``kernel.eval`` span."""
+    compiled = compiled_span_ids(spans)
     groups: Dict[str, List[Dict[str, Any]]] = defaultdict(list)
     for sp in _kernel_spans(spans):
         key = sp.get("args", {}).get("kernel_key")
@@ -283,7 +300,7 @@ def report_cost(spans) -> None:
     for key in sorted(groups):
         sps = groups[key]
         steady = [sp for sp in sps
-                  if not sp.get("args", {}).get("first_call")]
+                  if sp.get("args", {}).get("span_id") not in compiled]
         timed = steady if steady else sps  # single-call bucket: use it
         mean_us = sum(sp.get("dur", 0.0) for sp in timed) / len(timed)
         a = sps[0].get("args", {})
@@ -334,9 +351,25 @@ def check_cost_attribution(spans) -> List[str]:
     return problems
 
 
+_COUNTER_ATTRS = ("n_iters", "n_expanded")
+
+
+def check_counters(spans) -> List[str]:
+    """All-or-none, counter by counter: once any ``kernel.eval`` span
+    carries one of the evaluator's counters, every one must (MapReduceMP
+    stamps ``n_iters`` alone)."""
+    kspans = _kernel_spans(spans)
+    return [f"kernel.eval span {sp.get('args', {}).get('span_id')} lacks "
+            f"counter attr {k}"
+            for k in _COUNTER_ATTRS
+            if any(k in sp.get("args", {}) for sp in kspans)
+            for sp in kspans if k not in sp.get("args", {})]
+
+
 def check(trace) -> int:
     """CI gate: 0 iff the trace is non-empty, well-nested, every query
-    span closed, and every recorded ranking score-consistent."""
+    span closed, every recorded ranking score-consistent, and the
+    ``kernel.eval`` cost and counter attrs all-or-none."""
     spans, decisions = trace["spans"], trace["decisions"]
     errors: List[str] = []
     if not spans:
@@ -372,6 +405,7 @@ def check(trace) -> int:
                           f"children but zero duration (never closed?)")
     errors.extend(verify_rankings(decisions))
     errors.extend(check_cost_attribution(spans))
+    errors.extend(check_counters(spans))
     if errors:
         for e in errors[:20]:
             print(f"CHECK FAIL: {e}", file=sys.stderr)
