@@ -275,6 +275,12 @@ def make_partition_evaluator(node_pad: int, ell_width: int, cfg: EngineConfig):
         out_rows, (out_step, out_dest), out_n, overflow = _append(
             out_rows, (out_step, out_dest), out_n, work_rows,
             (work_step, dest0), outm0, overflow)
+        # From here on every valid work row is active (step < n_steps, next
+        # frontier vertex core-local), so the loop reads the active set off
+        # ``wv`` and derives the frontier of the EB rows it selects only.
+        # Rows enter the buffer just here and as ``keep`` rows in ``body``,
+        # whose ``local`` is this same test; a new way of writing rows into
+        # the buffer that skips ``keep`` would break the invariant.
         work_valid = work_valid & act0
 
         state = (work_rows, work_step, work_valid, comp_rows, comp_n,
@@ -282,22 +288,21 @@ def make_partition_evaluator(node_pad: int, ell_width: int, cfg: EngineConfig):
                  jnp.int32(0), jnp.int32(0))
 
         def cond(st):
-            wr, ws, wv, *_, it, _nx = st
-            act, _, _ = _frontier_local(wr, ws, wv, plan, n_steps, g2l_row, n_core)
-            return jnp.any(act) & (it < cfg.max_inner_iters)
+            _wr, _ws, wv, *_, it, _nx = st
+            return jnp.any(wv) & (it < cfg.max_inner_iters)
 
         def body(st):
             (wr, ws, wv, cr, cn, orr, os_, od, on, ovf, it, nx) = st
-            act, lidx, _ = _frontier_local(wr, ws, wv, plan, n_steps, g2l_row, n_core)
             # pick up to EB active rows: top_k on the mask is O(WT log EB)
             # vs the original full argsort's O(WT log WT) (§Perf-D2)
-            _, sel = jax.lax.top_k(act.astype(jnp.int32), EB)
-            m = jnp.take(act, sel)
+            _, sel = jax.lax.top_k(wv.astype(jnp.int32), EB)
             rows_b = jnp.take(wr, sel, axis=0)
             step_b = jnp.take(ws, sel)
-            lidx_b = jnp.take(lidx, sel)
+            valid_b = jnp.take(wv, sel)
+            m, lidx_b, _ = _frontier_local(rows_b, step_b, valid_b, plan,
+                                           n_steps, g2l_row, n_core)
             # consume them
-            wv = wv.at[sel].set(jnp.take(wv, sel) & ~m)
+            wv = wv.at[sel].set(valid_b & ~m)
 
             (ok, dg, ns, nr, done_t, keep_t, outm_t, dest_t) = _expand_classify(
                 rows_b, step_b, lidx_b, m, part, g2l_row, owner, aux,

@@ -345,7 +345,10 @@ class QueryScheduler:
         engine: OPATEngine = self.session.engine
         beval = engine.batched_evaluator()
         rng = np.random.default_rng(self.seed)
-        limit = 64 * self.pg.k * max(1, len(self._jobs))
+        # per job, as OPAT's per-query guard: ``self.loads`` spans the
+        # scheduler's life and ``self._jobs`` only the pending set, so a
+        # long-lived streaming scheduler must not weigh one by the other
+        limit = 64 * self.pg.k
         rounds = 0
         while True:
             if max_rounds is not None and rounds >= max_rounds:
@@ -353,8 +356,9 @@ class QueryScheduler:
             waiters, ranked = self._rank(rng)
             if not waiters:
                 break
-            if len(self.loads) >= limit:
-                raise RuntimeError("scheduler exceeded max partition loads "
+            if any(j.state.iterations >= limit
+                   for js in waiters.values() for j in js):
+                raise RuntimeError("a query exceeded max partition loads "
                                    f"({limit}); likely a routing bug")
             pid = int(ranked[0])
             batch = waiters[pid]
@@ -450,7 +454,10 @@ class QueryScheduler:
         k = self.pg.k
         p = engine.p
         rng = np.random.default_rng(self.seed)
-        limit = 64 * self.pg.k * max(1, len(self._jobs))
+        # per job, as OPAT's per-query guard: ``self.loads`` spans the
+        # scheduler's life and ``self._jobs`` only the pending set, so a
+        # long-lived streaming scheduler must not weigh one by the other
+        limit = 64 * self.pg.k
         rounds = 0
         while True:
             if max_rounds is not None and rounds >= max_rounds:
@@ -458,8 +465,9 @@ class QueryScheduler:
             waiters, ranked = self._rank(rng)
             if not waiters:
                 break
-            if len(self.loads) >= limit:
-                raise RuntimeError("scheduler exceeded max partition loads "
+            if any(j.state.iterations >= limit
+                   for js in waiters.values() for j in js):
+                raise RuntimeError("a query exceeded max partition loads "
                                    f"({limit}); likely a routing bug")
             # canonical sorted order + first-pid padding, exactly as the
             # per-query TMP loop: the stacked store key is then

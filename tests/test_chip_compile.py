@@ -14,6 +14,7 @@ file, never while a module is imported, so every pytest worker collects
 the same tests and only the worker given this file loads the TPU library.
 """
 import os
+import re
 
 import numpy as np
 import pytest
@@ -102,19 +103,46 @@ def test_label_histogram_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("use_pallas", [False, True],
-                         ids=["jnp", "fused_kernel"])
-def test_partition_evaluator_compiles_for_v5e(one_chip, monkeypatch,
-                                              use_pallas):
-    """The served evaluator at a small geometry.  ops chooses interpret
-    mode from the backend, which is the CPU here; the fused case steers
-    it to the compiled kernel, as on the chip."""
-    monkeypatch.setattr(ops, "_interpret", lambda: False)
-    np_, w, v, cap = 1024, 8, 4096, 1024
-    cfg = EngineConfig(cap=cap, use_pallas=use_pallas)
+# the evaluator's small geometry: the while loop's work buffer holds
+# EVAL_CAP incoming rows and EVAL_NP fresh seeds
+EVAL_NP, EVAL_W, EVAL_V, EVAL_CAP = 1024, 8, 4096, 1024
+WT = EVAL_CAP + EVAL_NP
+
+_OP_NAME = re.compile(r'op_name="([^"]*/while/(?:cond|body)/[^"]*)"')
+_OPCODE = re.compile(r"\s[a-z][a-z0-9-]*\(")
+_DIMS = re.compile(r"[a-z]+[0-9]*\[([0-9,]*)\]")
+
+
+def loop_gathers(hlo_text, rows):
+    """Instructions of a while loop's cond or body that XLA lowered from a
+    gather and whose result (or a part of a tuple result) has ``rows``
+    rows."""
+    found = []
+    for line in hlo_text.splitlines():
+        m = _OP_NAME.search(line)
+        if not m or not m.group(1).endswith("gather") or " = " not in line:
+            continue
+        rhs = line.split(" = ", 1)[1]
+        op = _OPCODE.search(rhs)
+        shapes = _DIMS.findall(rhs[: op.start()] if op else rhs)
+        if any(dims.split(",")[0] == str(rows) for dims in shapes):
+            found.append(line.strip())
+    return found
+
+
+@pytest.fixture(scope="module", params=[False, True],
+                ids=["jnp", "fused_kernel"])
+def evaluator_hlo(one_chip, request):
+    """The served evaluator compiled at a small geometry, with and without
+    the fused kernel; its HLO text.  ops chooses interpret mode from the
+    backend, which is the CPU here; the fused case steers it to the
+    compiled kernel, as on the chip."""
+    use_pallas = request.param
+    cfg = EngineConfig(cap=EVAL_CAP, use_pallas=use_pallas)
     S = cfg.s_pad
     i32 = lambda *shape: _shape(one_chip, shape)
     f32 = lambda *shape: _shape(one_chip, shape, jnp.float32)
+    np_, w = EVAL_NP, EVAL_W
     part = dict(pid=i32(), n_core=i32(), node_gid=i32(np_),
                 node_label=i32(np_), node_value=f32(np_),
                 ell_dst=i32(np_, w), ell_label=i32(np_, w),
@@ -127,8 +155,28 @@ def test_partition_evaluator_compiles_for_v5e(one_chip, monkeypatch,
         dst_label=i32(S), dst_value_op=i32(S), dst_value=f32(S),
         closes_cycle=i32(S))
     evaluate = make_partition_evaluator(np_, w, cfg)
-    compiled = evaluate.lower(
-        part, i32(v), i32(v), plan, i32(), i32(cap, cfg.q_pad), i32(cap),
-        _shape(one_chip, (cap,), jnp.bool_), _shape(one_chip, (), jnp.bool_),
-    ).compile()
-    assert ("tpu_custom_call" in compiled.as_text()) == use_pallas
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_interpret", lambda: False)
+        compiled = evaluate.lower(
+            part, i32(EVAL_V), i32(EVAL_V), plan, i32(),
+            i32(EVAL_CAP, cfg.q_pad), i32(EVAL_CAP),
+            _shape(one_chip, (EVAL_CAP,), jnp.bool_),
+            _shape(one_chip, (), jnp.bool_),
+        ).compile()
+    return use_pallas, compiled.as_text()
+
+
+def test_partition_evaluator_compiles_for_v5e(evaluator_hlo):
+    """The served evaluator at a small geometry."""
+    use_pallas, hlo = evaluator_hlo
+    assert ("tpu_custom_call" in hlo) == use_pallas
+
+
+def test_evaluator_loop_gathers_no_work_buffer_rows(evaluator_hlo):
+    """A trip of the while loop expands at most ``expand_block`` rows, so
+    nothing in its cond or body gathers over the whole WT-row work buffer
+    (a per-row frontier over every work row cost most of the evaluator's
+    device time on the chip)."""
+    _, hlo = evaluator_hlo
+    assert "/while/body/" in hlo and "/while/cond/" in hlo
+    assert loop_gathers(hlo, WT) == []

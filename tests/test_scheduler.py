@@ -232,6 +232,29 @@ def test_streaming_admission_two_rounds(setup):
     assert r2.load_stats.warm_loads > 0
 
 
+@pytest.mark.parametrize("engine_name", ["opat", "traditional"])
+def test_long_lived_scheduler_serves_past_the_load_guard(setup, engine_name):
+    """A streaming scheduler pumped one round at a time, one query pending
+    at a time, keeps serving after its lifetime loads pass the runaway
+    guard's per-query bound (64 loads a partition): the guard weighs a
+    query's own loads, not everything the scheduler ever loaded."""
+    g, dqueries, refs = setup
+    sess = make_session(g, engine_name, k=1)
+    sched = sess.scheduler()
+    bound = 64 * sess.pg.k
+    served = 0
+    while len(sched.loads) <= bound:
+        dq = dqueries[served % len(dqueries)]
+        qid = sched.admit(dq)
+        results = []
+        while sched.n_pending:
+            results += sched.run(max_rounds=1).results
+        assert [r.qid for r in results] == [qid]
+        assert np.array_equal(results[0].answers, refs[dq.name])
+        served += 1
+    assert served > 1
+
+
 def test_scheduler_refuses_rebound_session(setup):
     """GraphSession.repartition() rebinds store/layout; a scheduler built
     against the old binding must refuse loudly instead of mixing pids."""
